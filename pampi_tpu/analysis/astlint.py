@@ -7,9 +7,10 @@ Every rule guards a contract that past PRs fixed by hand at least once:
                  accessor records every variable in one inventory, so a
                  rogue read is a knob invisible to the docs, the lint,
                  and the flag-off identity tests.
-  raw-shard-map  `jax.shard_map` / `jax.experimental.shard_map` used
-                 outside `parallel/comm.compat_shard_map` — the version
-                 shim lives there ONLY (two past PRs routed stragglers).
+  raw-shard-map  `jax.shard_map`, or any `shard_map` module import,
+                 used outside `parallel/comm.compat_shard_map` — the one
+                 call site lives there ONLY (two past PRs routed
+                 stragglers).
   np-in-traced   `np.*` inside a traced closure — a def nested in a
                  `_build_*`/`make_*` builder, the repo's convention for
                  the functions jit/while_loop traces per step (builder
@@ -148,8 +149,8 @@ class _Linter(ast.NodeVisitor):
         self.out: list[Violation] = []
         # stack of (function name, is_traced_context)
         self._funcs: list[tuple[str, bool]] = []
-        # local aliases of the jax.experimental.shard_map MODULE
-        # (`import jax.experimental.shard_map as sm` -> "sm")
+        # local aliases of a `shard_map` MODULE
+        # (`import jax._src.shard_map as sm` -> "sm")
         self._sm_aliases: set[str] = set()
 
     # -- helpers --------------------------------------------------------
@@ -274,24 +275,24 @@ class _Linter(ast.NodeVisitor):
 
     def visit_ImportFrom(self, node: ast.ImportFrom) -> None:
         mod = node.module or ""
-        if mod.startswith("jax.experimental.shard_map") or (
+        if mod.split(".")[-1] == "shard_map" or (
             mod in ("jax", "jax.experimental")
             and any(a.name == "shard_map" for a in node.names)
         ):
             self._emit(node, RAW_SHARD_MAP,
                        f"importing shard_map from {mod} — use "
-                       "parallel/comm.compat_shard_map (the one version "
-                       "shim)")
+                       "parallel/comm.compat_shard_map (the one call "
+                       "site)")
         self.generic_visit(node)
 
     def visit_Import(self, node: ast.Import) -> None:
         for a in node.names:
-            if a.name.startswith("jax.experimental.shard_map"):
+            if a.name.split(".")[-1] == "shard_map":
                 if a.asname:
                     self._sm_aliases.add(a.asname)
                 self._emit(node, RAW_SHARD_MAP,
                            f"importing {a.name} — use parallel/comm."
-                           "compat_shard_map (the one version shim)")
+                           "compat_shard_map (the one call site)")
         self.generic_visit(node)
 
     def visit_ExceptHandler(self, node: ast.ExceptHandler) -> None:
@@ -406,7 +407,7 @@ def env_inventory(root: str) -> dict[str, list[str]]:
                 if node.args and isinstance(node.args[0], ast.Constant) \
                         and isinstance(node.args[0].value, str):
                     var = node.args[0].value
-                    if var.startswith("PAMPI_"):
+                    if var.startswith(("PAMPI_", "JAX_")):
                         inv.setdefault(var, []).append(
                             f"{rel}:{node.lineno}")
     return inv

@@ -94,12 +94,14 @@ def prim_histogram(jaxpr) -> dict[str, int]:
 
 
 def host_callbacks(jaxpr) -> list[str]:
-    """Primitive names of host-callback eqns (debug_callback from
-    jax.debug.print, io_callback, pure_callback, legacy outside_call)."""
+    """Primitive names of host-callback eqns (debug_print from
+    jax.debug.print, debug_callback, io_callback, pure_callback, legacy
+    outside_call)."""
     return [
         e.primitive.name
         for e in iter_eqns(jaxpr)
-        if "callback" in e.primitive.name or e.primitive.name == "outside_call"
+        if "callback" in e.primitive.name
+        or e.primitive.name in ("debug_print", "outside_call")
     ]
 
 
@@ -120,7 +122,7 @@ def float_dtypes(jaxpr) -> set[str]:
     return out
 
 
-# `name_and_src_info=<kernel> at <file>:<line>` in pallas_call params:
+# `<kernel> at <file>:<line>` source info printed with pallas_call params:
 # the line number is SOURCE metadata, not program structure — an edit
 # that merely shifts a kernel def down the file must not read as trace
 # drift (found in round 20: every fused-config hash churned on a

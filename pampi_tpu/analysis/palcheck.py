@@ -42,9 +42,9 @@ index maps, memory spaces — and the kernel jaxpr's scratch operands):
                through another operand of the same call — the classic
                use-after-donation hazard.
 
-Diagnostics carry the kernel's own file:line (from the pallas_call's
-`name_and_src_info`), so a violation points at the kernel source, not at
-the solver that dispatched it.
+Diagnostics carry the kernel's own file:line (from the kernel jaxpr's
+`debug_info.func_src_info`), so a violation points at the kernel source,
+not at the solver that dispatched it.
 """
 
 from __future__ import annotations
@@ -77,14 +77,20 @@ def min_tile(dtype) -> tuple[int, int]:
     return {2: 16, 1: 32}.get(itemsize, 8), 128
 
 
-def block_extents(bm) -> tuple[int, ...]:
-    """`block_shape` as plain element extents: squeezed dims (spelled
-    `None` in the BlockSpec, a `Mapped` sentinel in the jaxpr param) are
-    extent 1 — one element per grid step along that dim."""
+def _size(s):
+    """One `block_shape` entry's element extent, or None for a squeezed
+    dim (`None` in the BlockSpec, a `Squeezed` entry in the jaxpr param;
+    sized dims arrive wrapped as `Blocked(block_size=n)`)."""
     import numpy as np
 
-    return tuple(int(s) if isinstance(s, (int, np.integer)) else 1
-                 for s in bm.block_shape)
+    size = getattr(s, "block_size", s)
+    return int(size) if isinstance(size, (int, np.integer)) else None
+
+
+def block_extents(bm) -> tuple[int, ...]:
+    """`block_shape` as plain element extents: squeezed dims are extent
+    1 — one element per grid step along that dim."""
+    return tuple(_size(s) or 1 for s in bm.block_shape)
 
 
 def _mspace(aval) -> str:
@@ -119,17 +125,17 @@ class Launch:
 
 def decode(eqn) -> Launch:
     gm = eqn.params["grid_mapping"]
-    nsi = eqn.params["name_and_src_info"]
-    m = _SRC_RE.search(getattr(nsi, "src_info", "") or "")
-    path, line = (m.group(1), int(m.group(2))) if m else ("<unknown>", 1)
     kernel_jaxpr = eqn.params["jaxpr"]
+    src = getattr(kernel_jaxpr.debug_info, "func_src_info", "") or ""
+    m = _SRC_RE.search(src)
+    path, line = (m.group(1), int(m.group(2))) if m else ("<unknown>", 1)
     nscratch = gm.num_scratch_operands
     scratch = [v.aval for v in kernel_jaxpr.invars[len(kernel_jaxpr.invars)
                                                    - nscratch:]] \
         if nscratch else []
-    mosaic = (eqn.params.get("compiler_params") or {}).get("mosaic", {})
+    mosaic = (eqn.params.get("compiler_params") or {}).get("mosaic_tpu")
     return Launch(
-        name=nsi.name,
+        name=eqn.params.get("name") or src.split(" at ")[0],
         path=path,
         line=line,
         grid=tuple(gm.grid),
@@ -138,7 +144,7 @@ def decode(eqn) -> Launch:
             gm.block_mappings[gm.num_inputs:gm.num_inputs + gm.num_outputs]),
         scratch_avals=scratch,
         aliases=tuple(eqn.params.get("input_output_aliases") or ()),
-        vmem_limit=mosaic.get("vmem_limit_bytes"),
+        vmem_limit=getattr(mosaic, "vmem_limit_bytes", None),
         num_index_operands=gm.num_index_operands,
         eqn=eqn,
     )
@@ -159,8 +165,7 @@ def eval_index_map(closed, grid_idx: tuple) -> tuple | None:
     """Concrete block indices for one grid point, or None when the map
     depends on a scalar-prefetch operand through real arithmetic (then
     the coverage check abstains instead of guessing)."""
-    import jax
-    import jax.core
+    from jax.extend.core import Literal, jaxpr_as_fun
 
     jaxpr = closed.jaxpr
     n = len(grid_idx)
@@ -168,7 +173,7 @@ def eval_index_map(closed, grid_idx: tuple) -> tuple | None:
         env = dict(zip(jaxpr.invars[:n], grid_idx))
         out = []
         for v in jaxpr.outvars:
-            if isinstance(v, jax.core.Literal):
+            if isinstance(v, Literal):
                 out.append(int(v.val))
             elif v in env:
                 out.append(int(env[v]))
@@ -181,7 +186,7 @@ def eval_index_map(closed, grid_idx: tuple) -> tuple | None:
 
         args = [np.asarray(i, dtype=v.aval.dtype)
                 for v, i in zip(jaxpr.invars, grid_idx)]
-        vals = jax.core.eval_jaxpr(jaxpr, closed.consts, *args)
+        vals = jaxpr_as_fun(closed)(*args)
         return tuple(int(v) for v in vals)
     return None
 
@@ -249,14 +254,14 @@ def check_launch(launch: Launch, budget: int | None = None,
         aval = bm.transformed_block_aval
         if _mspace(aval) not in ("vmem",):
             continue
-        array = bm.array_shape_dtype.shape
+        array = bm.array_aval.shape
         block = block_extents(bm)
         if len(block) < 2 or len(block) != len(array):
             continue
         # squeezed dims (extent 1 by iteration, not by windowing) are
         # the programmer's explicit layout choice — not a tiling bug
         squeezed = {d for d, s in enumerate(bm.block_shape)
-                    if block[d] != s}
+                    if _size(s) is None}
         sub, lane = min_tile(aval.dtype)
         for dim, need in ((len(block) - 1, lane), (len(block) - 2, sub)):
             if dim in squeezed:
@@ -284,7 +289,7 @@ def check_launch(launch: Launch, budget: int | None = None,
              )
     # --- grid × index-map coverage --------------------------------------
     for bm in launch.mappings:
-        array = bm.array_shape_dtype.shape
+        array = bm.array_aval.shape
         block = block_extents(bm)
         if len(block) != len(array):
             continue
@@ -320,19 +325,19 @@ def check_launch(launch: Launch, budget: int | None = None,
             continue
         bi, bo = launch.in_mappings[i], launch.out_mappings[o]
         same = (
-            bi.array_shape_dtype.shape == bo.array_shape_dtype.shape
-            and bi.array_shape_dtype.dtype == bo.array_shape_dtype.dtype
+            bi.array_aval.shape == bo.array_aval.shape
+            and bi.array_aval.dtype == bo.array_aval.dtype
             and tuple(bi.block_shape) == tuple(bo.block_shape)
             and str(bi.index_map_jaxpr) == str(bo.index_map_jaxpr)
         )
         if not same:
             how = ("index maps differ"
                    if tuple(bi.block_shape) == tuple(bo.block_shape)
-                   and bi.array_shape_dtype == bo.array_shape_dtype
+                   and bi.array_aval == bo.array_aval
                    else f"input block {tuple(bi.block_shape)} of "
-                        f"{bi.array_shape_dtype.shape} vs output block "
+                        f"{bi.array_aval.shape} vs output block "
                         f"{tuple(bo.block_shape)} of "
-                        f"{bo.array_shape_dtype.shape}")
+                        f"{bo.array_aval.shape}")
             emit(RULE_ALIAS,
                  f"alias ({i} -> {o}) windows differ ({how}) — the "
                  "donated buffer is rewritten through a different window "
@@ -433,7 +438,7 @@ def restricted_grid_entries():
     br, _h, wp, nb = nf.fused_deep_layout_2d(jl, il, dt, ext_pad,
                                              block_rows=8)
     plan = ovl.region_plan((jl, il), nf.OVERLAP_RIM, ext_pad, br, nb, wp,
-                           (True, False))
+                           (True, False), align=8)
     out = []
     for name, bands in (("interior", plan["int_bands"]),
                         ("boundary", plan["bnd_bands"]), ("full", None)):

@@ -120,7 +120,7 @@ def eqn_src(eqn) -> tuple[str, int]:
     try:
         from jax._src import source_info_util
 
-        fr = source_info_util.user_frame(eqn.source_info)
+        fr = source_info_util.user_frame(eqn.source_info.traceback)
     except (ImportError, AttributeError):
         fr = None
     if fr is None:

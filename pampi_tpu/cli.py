@@ -95,10 +95,18 @@ def _try_build(build):
 
 
 def _run(argv) -> int:
+    from .utils.params import Parameter, read_parameter
 
-    from .utils.params import Parameter, read_parameter, print_parameter
+    return run(read_parameter(argv[1], Parameter()), config=argv[1])[0]
 
-    param = read_parameter(argv[1], Parameter())
+
+def run(param, config: str = "", write: bool = True):
+    """One whole CLI run of an already-read .par: commInit, compile cache,
+    config echo, solve, outputs (`write=False` skips the output files),
+    walltime. Returns (exit code, solver); solver is None when the run
+    stopped before one was built. chip_smoke.py drives the CLI through
+    here so it can check the fields the run ends with."""
+    from .utils.params import print_parameter
 
     # commInit before anything touches devices: under a PAMPI_COORDINATOR
     # launch this joins the process group and makes jax.devices() global;
@@ -127,12 +135,12 @@ def _run(argv) -> int:
         print_parameter(param)
         prof.init()
         telemetry.start_run(
-            tool="cli", config=argv[1], problem=param.name,
+            tool="cli", config=config, problem=param.name,
             grid=[param.kmax, param.jmax, param.imax],
             solver=param.tpu_solver, dtype=param.tpu_dtype,
         )
         try:
-            return _dispatch(param, prof)
+            return _dispatch(param, prof, write)
         finally:
             # always stop an open XProf trace and print the region table, even
             # when the solver or a writer raises — that's the run worth
@@ -203,7 +211,7 @@ def _resume_after_death(param, exc, is3d: bool):
     return solver
 
 
-def _dispatch(param, prof) -> int:
+def _dispatch(param, prof, write: bool = True):
     from .utils.timing import get_timestamp
 
     if param.tpu_solver not in ("sor", "mg", "fft", "sor_lex", "sor_rba",
@@ -213,7 +221,7 @@ def _dispatch(param, prof) -> int:
             f"got {param.tpu_solver!r}",
             file=sys.stderr,
         )
-        return 1
+        return 1, None
 
     from .utils.params import is_3d_config
 
@@ -227,14 +235,14 @@ def _dispatch(param, prof) -> int:
             "NS problems take sor|sor_lex|mg|fft",
             file=sys.stderr,
         )
-        return 1
+        return 1, None
     if param.tpu_solver == "sor_lex" and ns3d:
         print(
             "Error: tpu_solver sor_lex is 2-D only (Poisson and NS-2D); "
             "NS-3D takes sor|mg|fft",
             file=sys.stderr,
         )
-        return 1
+        return 1, None
 
     if param.tpu_chunk < 0 or param.tpu_lookahead < 0:
         print(
@@ -242,7 +250,7 @@ def _dispatch(param, prof) -> int:
             f"(got {param.tpu_chunk}, {param.tpu_lookahead})",
             file=sys.stderr,
         )
-        return 1
+        return 1, None
 
     if (param.tpu_recover_ring < 0 or param.tpu_recover_max < 1
             or not 0.0 < param.tpu_recover_dt_scale <= 1.0
@@ -255,7 +263,7 @@ def _dispatch(param, prof) -> int:
             f"{param.tpu_recover_dt_scale}, {param.tpu_retry_replenish})",
             file=sys.stderr,
         )
-        return 1
+        return 1, None
 
     if param.tpu_coord not in ("auto", "on", "off") \
             or param.tpu_ckpt_elastic not in (0, 1):
@@ -264,7 +272,7 @@ def _dispatch(param, prof) -> int:
             f"0|1 (got {param.tpu_coord!r}, {param.tpu_ckpt_elastic})",
             file=sys.stderr,
         )
-        return 1
+        return 1, None
 
     if param.tpu_coord_timeout < 0 or param.tpu_dead_resume not in (0, 1):
         print(
@@ -273,7 +281,7 @@ def _dispatch(param, prof) -> int:
             f"{param.tpu_coord_timeout}, {param.tpu_dead_resume})",
             file=sys.stderr,
         )
-        return 1
+        return 1, None
 
     from .utils import faultinject as _fi
 
@@ -293,7 +301,7 @@ def _dispatch(param, prof) -> int:
             f"|octants, got {param.tpu_sor_layout!r}",
             file=sys.stderr,
         )
-        return 1
+        return 1, None
 
     if param.obstacles.strip() and param.name.startswith("poisson"):
         # refuse rather than silently simulate an empty box
@@ -301,7 +309,7 @@ def _dispatch(param, prof) -> int:
             "Error: the obstacles key is supported for NS problems only",
             file=sys.stderr,
         )
-        return 1
+        return 1, None
 
     if param.name.startswith("poisson"):
         from .models.poisson import PoissonSolver
@@ -316,15 +324,16 @@ def _dispatch(param, prof) -> int:
 
         solver = _try_build(build)
         if solver is None:
-            return 1
+            return 1, None
         start = get_timestamp()
         with prof.region("solve"):
             it, res = solver.solve()
         end = get_timestamp()
         # parity: solver prints "%d " (no newline), main appends Walltime
         print(f"{it} ", end="")
-        with prof.region("writeResult"):
-            solver.write_result("p.dat")
+        if write:
+            with prof.region("writeResult"):
+                solver.write_result("p.dat")
         print("Walltime %.2fs" % (end - start))
     elif param.name in ("dcavity", "canal", "canal_obstacle", "dcavity3d",
                         "canal3d"):
@@ -338,7 +347,7 @@ def _dispatch(param, prof) -> int:
                 f"got {param.tpu_vtk!r}",
                 file=sys.stderr,
             )
-            return 1
+            return 1, None
 
         def build():
             if is3d:
@@ -361,7 +370,7 @@ def _dispatch(param, prof) -> int:
 
         solver = _try_build(build)
         if solver is None:
-            return 1
+            return 1, None
         if is3d:
             from .utils import flags as _flags
 
@@ -381,7 +390,7 @@ def _dispatch(param, prof) -> int:
                 # config-class error: same one-line convention as _try_build
                 print(f"Error: cannot restart from {param.tpu_restart}: {exc}",
                       file=sys.stderr)
-                return 1
+                return 1, None
             print(f"Restarted from {param.tpu_restart} at t={solver.t:.4f}")
         if param.tpu_checkpoint:
             from .parallel.coordinator import coord_armed
@@ -408,26 +417,26 @@ def _dispatch(param, prof) -> int:
             # survivors when the run armed the elastic resume path.
             solver = _resume_after_death(param, exc, is3d)
             if solver is None:
-                return 3
+                return 3, None
         end = get_timestamp()
         print("Solution took %.2fs" % (end - start))
         if param.tpu_checkpoint:
             ckpt.writer_for(param)(param.tpu_checkpoint, solver)
-        with prof.region("writeResult"):
-            if is3d:
-                if param.tpu_vtk == "sharded":
+        if write:
+            with prof.region("writeResult"):
+                if not is3d:
+                    solver.write_result("pressure.dat", "velocity.dat")
+                elif param.tpu_vtk == "sharded":
                     if hasattr(solver, "write_result_sharded"):
                         solver.write_result_sharded()
                     else:  # single device: binary writer = same bytes
                         solver.write_result(fmt="binary")
                 else:
                     solver.write_result(fmt=param.tpu_vtk)
-            else:
-                solver.write_result("pressure.dat", "velocity.dat")
     else:
         print(f"Unknown problem name: {param.name}", file=sys.stderr)
-        return 1
-    return 0
+        return 1, None
+    return 0, solver
 
 
 if __name__ == "__main__":

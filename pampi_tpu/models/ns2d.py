@@ -460,6 +460,9 @@ class NS2DSolver:
         record("ns2d_p_layout",
                "folded (solve shares the fused padded layout)"
                if solve_pad is not None else "explicit pad/unpad")
+        if solve_pad is not None:
+            record("sor2d", f"pallas_tblock (n_inner={param.tpu_sor_inner}"
+                            ", folded)")
         solve = self._make_solve(backend) if solve_pad is None else solve_pad
         if solve_pad is not None:
             # time_solve_ms must time THIS padded-layout solve, not the

@@ -914,8 +914,11 @@ class NS2DDistSolver:
             # column axis is partitioned) for the boundary half
             br_, _hh, wp_, nb_ = nf.fused_deep_layout_2d(
                 jl, il, dtype, H - 1)
+            from ..ops.sor_pallas import _align
+
             plan = _ovl.region_plan((jl, il), OVERLAP_RIM, H - 1,
-                                    br_, nb_, wp_, part)
+                                    br_, nb_, wp_, part,
+                                    align=_align(dtype))
             restrict = _dispatch.resolve_overlap_restrict(
                 param, "overlap_grid_ns2d_dist", plan)
             self._overlap_plan = plan if restrict else None
@@ -1377,12 +1380,12 @@ class NS2DDistSolver:
         """(u, v, p, t, nt[, metrics]) matching the built chunk's arity
         (the NS-2D convention — see models/ns2d.initial_state)."""
         time_dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-        state = (self.u, self.v, self.p,
-                 jnp.asarray(self.t, time_dtype),
-                 jnp.asarray(self.nt, jnp.int32))
+        scalars = (jnp.asarray(self.t, time_dtype),
+                   jnp.asarray(self.nt, jnp.int32))
         if self._metrics:
-            state = state + (_tm.metrics_init(),)
-        return state
+            scalars = scalars + (_tm.metrics_init(),)
+        return (self.u, self.v, self.p) + tuple(
+            self.comm.replicate(x) for x in scalars)
 
     def run(self, progress: bool = True, on_sync=None) -> None:
         """The dist drive loop now IS models/_driver.drive_chunks (PR 4):

@@ -99,16 +99,20 @@ def write_vtk_result(param, grid, fields, path=None, fmt: str = "ascii") -> None
     writer.close()
 
 
-def _use_pallas_3d(backend: str, dtype) -> bool:
-    """models/poisson._use_pallas with the 3-D kernel's probe."""
-    from .poisson import _use_pallas
+def _pallas_why_not_3d(backend: str, dtype):
+    """models/poisson._pallas_why_not with the 3-D kernel's probe."""
+    from .poisson import _pallas_why_not
 
     def probe():
         from ..ops import sor3d_pallas as sp3
 
         return sp3.pltpu is not None and sp3.probe_pallas_3d()
 
-    return _use_pallas(backend, dtype, probe=probe)
+    return _pallas_why_not(backend, dtype, probe=probe)
+
+
+def _use_pallas_3d(backend: str, dtype) -> bool:
+    return _pallas_why_not_3d(backend, dtype) is None
 
 
 def make_pressure_solve_3d(imax, jmax, kmax, dx, dy, dz, omega, eps, itermax,
@@ -149,7 +153,10 @@ def make_pressure_solve_3d(imax, jmax, kmax, dx, dy, dz, omega, eps, itermax,
             f"3-D SOR layout must be auto|checkerboard|octants, got "
             f"{layout!r} (quarters is the 2-D layout)"
         )
-    use_pallas = _use_pallas_3d(backend, dtype)
+    from ..utils.dispatch import record
+
+    why = _pallas_why_not_3d(backend, dtype)
+    use_pallas = why is None
     even = imax % 2 == 0 and jmax % 2 == 0 and kmax % 2 == 0
     if layout == "octants" and not even:
         raise ValueError("octant layout needs even imax, jmax, kmax")
@@ -168,6 +175,7 @@ def make_pressure_solve_3d(imax, jmax, kmax, dx, dy, dz, omega, eps, itermax,
                 n_inner=n_inner, block_k=bko,
             )
             if rb_iter is not None:
+                record("sor3d", f"pallas_octants (n_inner={n_inner})")
                 return sp3.make_octants_solve_loop(
                     rb_iter, bko, n_inner, norm, eps, itermax,
                     kmax, jmax, imax, dtype,
@@ -184,7 +192,8 @@ def make_pressure_solve_3d(imax, jmax, kmax, dx, dy, dz, omega, eps, itermax,
         # halo depth: the kernel would recompute halos >3x over and likely
         # overflow VMEM — the jnp path is the better program
         bk = sp3.pick_block_k(kmax, jmax, imax, dtype, n_inner)
-        use_pallas = not sp3.block_k_degenerate(bk, kmax, n_inner)
+        if sp3.block_k_degenerate(bk, kmax, n_inner):
+            use_pallas, why = False, "block_k degenerate"
 
     if use_pallas:
         from ..ops import sor3d_pallas as sp3
@@ -194,11 +203,13 @@ def make_pressure_solve_3d(imax, jmax, kmax, dx, dy, dz, omega, eps, itermax,
         )
         if rb_iter is None:
             raise ValueError("pallas 3-D backend unavailable")
+        record("sor3d", f"pallas_tblock (n_inner={n_inner})")
         return sp3.make_tblock_solve_loop(
             rb_iter, block_k, n_inner, norm, eps, itermax,
             kmax, jmax, imax, dtype,
         )
 
+    record("sor3d", f"jnp ({why})")
     factor, idx2, idy2, idz2 = sor_coefficients_3d(dx, dy, dz, omega)
     odd = checkerboard_mask_3d(kmax, jmax, imax, 1, dtype)
     even = checkerboard_mask_3d(kmax, jmax, imax, 0, dtype)

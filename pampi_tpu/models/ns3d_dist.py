@@ -1255,12 +1255,12 @@ class NS3DDistSolver:
         """(u, v, w, p, t, nt[, metrics]) matching the built chunk's arity
         (the NS-2D convention — see models/ns2d.initial_state)."""
         time_dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-        state = (self.u, self.v, self.w, self.p,
-                 jnp.asarray(self.t, time_dtype),
-                 jnp.asarray(self.nt, jnp.int32))
+        scalars = (jnp.asarray(self.t, time_dtype),
+                   jnp.asarray(self.nt, jnp.int32))
         if self._metrics:
-            state = state + (_tm.metrics_init(),)
-        return state
+            scalars = scalars + (_tm.metrics_init(),)
+        return (self.u, self.v, self.w, self.p) + tuple(
+            self.comm.replicate(x) for x in scalars)
 
     def run(self, progress: bool = True, on_sync=None) -> None:
         """The shared drive loop (models/_driver.drive_chunks) — see

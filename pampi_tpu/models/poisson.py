@@ -54,22 +54,34 @@ def init_fields(param: Parameter, problem: int = 2, dtype=jnp.float64):
     return jnp.asarray(p, dtype=dtype), jnp.asarray(rhs, dtype=dtype)
 
 
-def _use_pallas(backend: str, dtype=jnp.float32, probe=None) -> bool:
+def _pallas_why_not(backend: str, dtype=jnp.float32, probe=None):
     """Backend-decision contract shared by every pallas-dispatched solver:
-    explicit "pallas" forces, "auto" requires a real TPU, a Mosaic-lowerable
-    dtype, and a passing one-time probe. `probe` defaults to the 2-D kernel's
-    smoke test; the 3-D solver passes its own (models/ns3d._use_pallas_3d)."""
+    None when the Pallas kernel runs, else why the jnp path does. Explicit
+    "pallas" forces, "auto" requires a real TPU, a Mosaic-lowerable dtype,
+    and a passing one-time probe (which raises on a TPU where the kernel
+    family fails). `probe` defaults to the 2-D kernel's smoke test; the
+    3-D solver passes its own (models/ns3d._use_pallas_3d)."""
     if backend == "pallas":
-        return True
-    if backend != "auto" or jax.default_backend() != "tpu":
-        return False
+        return None
+    if backend == "jnp":
+        return "retry fallback backend"
+    if backend != "auto":
+        return f"backend {backend!r}"
+    if jax.default_backend() != "tpu":
+        return "no TPU"
     if jnp.dtype(dtype).itemsize > 4:
-        return False  # Mosaic has no f64; XLA emulates it, pallas can't
+        return "dtype not Mosaic-lowerable"  # XLA emulates f64, pallas can't
     if probe is None:
         from ..ops import sor_pallas as sp
 
-        return sp.pltpu is not None and sp.probe_pallas()
-    return probe()
+        ok = sp.pltpu is not None and sp.probe_pallas()
+    else:
+        ok = probe()
+    return None if ok else "probe failed"
+
+
+def _use_pallas(backend: str, dtype=jnp.float32, probe=None) -> bool:
+    return _pallas_why_not(backend, dtype, probe) is None
 
 
 def _try_quarters(imax, jmax, dx, dy, omega, dtype, n_inner, layout):
@@ -131,7 +143,10 @@ def make_rb_loop(imax, jmax, dx, dy, omega, dtype, backend: str = "auto",
             f"2-D SOR layout must be auto|checkerboard|quarters, got "
             f"{layout!r} (octants is the 3-D layout)"
         )
-    if _use_pallas(backend, dtype):
+    from ..utils.dispatch import record
+
+    why = _pallas_why_not(backend, dtype)
+    if why is None:
         from ..ops import sor_pallas as sp
 
         q = _try_quarters(imax, jmax, dx, dy, omega, dtype, n_inner, layout)
@@ -153,6 +168,7 @@ def make_rb_loop(imax, jmax, dx, dy, omega, dtype, backend: str = "auto",
             def post(xq):
                 return sp.unpad_quarters(xq, jmax, imax, h)
 
+            record("sor2d", f"pallas_quarters (n_inner={n_inner})")
             return step, prep, post, n_inner
         kernel = "tblock" if n_inner > 1 else "fused"
         try:
@@ -160,6 +176,7 @@ def make_rb_loop(imax, jmax, dx, dy, omega, dtype, backend: str = "auto",
                 imax, jmax, dx, dy, omega, dtype, kernel=kernel,
                 n_inner=n_inner,
             )
+            record("sor2d", f"pallas_tblock (n_inner={n_inner})")
             return step, prep, post, n_inner
         except ValueError:
             if backend == "pallas":
@@ -167,7 +184,8 @@ def make_rb_loop(imax, jmax, dx, dy, omega, dtype, backend: str = "auto",
             # VMEM-infeasible on this grid (tblock_feasible): the safe
             # fallback is jnp — the checkerboard kernel would crash Mosaic
             # at first dispatch on the same grids that trip quarters
-            pass
+            why = "tblock VMEM-infeasible"
+    record("sor2d", f"jnp ({why})")
     step = make_rb_step(imax, jmax, dx, dy, omega, dtype, backend="jnp")
     ident = lambda x: x  # noqa: E731
     return step, ident, ident, 1
@@ -519,7 +537,10 @@ class PoissonSolver:
             ):
                 raise  # no pallas in play — genuine error, don't re-run it
             # shape-specific pallas failure the dispatcher probe missed:
-            # fall back to the always-available jnp path (same arithmetic)
+            # fall back to the always-available jnp path (same arithmetic),
+            # on the flight record like the chunk driver's fallback
+            _tm.emit("retry", fault="pallas", action="jnp_fallback",
+                     what="poisson solve")
             self._backend = "jnp"
             self._solve = jax.jit(self._make_solve(backend="jnp"))
             p, res, it = self._solve(self.p, self.rhs)

@@ -66,6 +66,15 @@ from .sor_pallas import (
 )
 
 
+# The fused cycle does not lower for a TPU on the installed toolchain:
+# `_restrict_plane` puts an in-kernel dynamic_update_slice ("Unimplemented
+# primitive in Pallas TPU lowering") in front of a 2x2 reshape that Mosaic
+# also refuses ("unsupported shape cast"); tests/test_chip_compile.py pins
+# the refusal. `tpu_mg_fused auto` keeps the per-level ladder on a TPU,
+# with this reason in the dispatch record; `on` still forces the cycle.
+TPU_BLOCKER = "fused cycle not Mosaic-lowerable: 2x2 restriction"
+
+
 def _pad8(n: int) -> int:
     return -(-n // 8) * 8
 
@@ -583,7 +592,7 @@ _PROBE_OK = None
 def probe_mg_fused() -> bool:
     """Compile and run a tiny two-level fused cycle on the real backend
     once per process; any failure (missing Mosaic op, lowering error)
-    makes every caller fall back to the jnp ladder."""
+    is raised on a TPU backend (utils/dispatch.probe_failed)."""
     global _PROBE_OK
     if _PROBE_OK is None:
         try:
@@ -598,13 +607,8 @@ def probe_mg_fused() -> bool:
             out = up(pstk, rstk, jnp.zeros_like(p))
             jax.block_until_ready(out)
             _PROBE_OK = True
-        except Exception as exc:  # lint: allow(broad-except) — probe contract: any failure means "don't dispatch"
-            import warnings
+        except Exception as exc:  # lint: allow(broad-except) — probe contract: raise on TPU, report unavailable elsewhere
+            from ..utils.dispatch import probe_failed
 
-            warnings.warn(
-                f"fused MG cycle kernel unavailable ({type(exc).__name__}); "
-                "falling back to the jnp ladder",
-                stacklevel=2,
-            )
-            _PROBE_OK = False
+            _PROBE_OK = probe_failed("the fused MG cycle kernels", exc)
     return _PROBE_OK

@@ -69,6 +69,7 @@ CFL scan.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -295,13 +296,17 @@ def _pre_kernel(
         def row_of(k):
             return k * br
     else:
+        # every band start and br share this factor: tell Mosaic, which
+        # cannot see through the select that a DMA row offset is aligned
+        step = math.gcd(br, *(s for s, _ in bands))
+
         def row_of(k):
             row, acc = None, 0
             for s, n in bands:
                 r = s + (k - acc) * br
                 row = r if row is None else jnp.where(k >= acc, r, row)
                 acc += n
-            return row
+            return pl.multiple_of(row, step) if step > 1 else row
 
     def load(k, s):
         r0 = row_of(k)
@@ -960,7 +965,7 @@ _PROBE_OK: bool | None = None
 def probe_fused_2d() -> bool:
     """One-time smoke test of the fused step-phase pair on a tiny grid on
     the real backend (the sor_pallas.probe_pallas contract): toolchain-wide
-    failures surface once and the dispatcher keeps the jnp chain."""
+    failures surface once, raised on a TPU backend."""
     global _PROBE_OK
     if _PROBE_OK is None:
         try:
@@ -978,13 +983,8 @@ def probe_fused_2d() -> bool:
             up, vp, um, _vm = post(offs, dt11, up, vp, fp, gp, z)
             float(um)  # force completion: async errors surface here
             _PROBE_OK = True
-        except Exception:  # lint: allow(broad-except) — probe contract: any failure means "don't dispatch"
-            import warnings
+        except Exception as exc:  # lint: allow(broad-except) — probe contract: raise on TPU, report unavailable elsewhere
+            from ..utils.dispatch import probe_failed
 
-            warnings.warn(
-                "fused NS step-phase kernels unavailable; keeping the jnp "
-                "phase chain",
-                stacklevel=2,
-            )
-            _PROBE_OK = False
+            _PROBE_OK = probe_failed("the fused NS-2D step-phase kernels", exc)
     return _PROBE_OK

@@ -604,16 +604,25 @@ def fused3_vmem_bytes(bk: int, h: int, jp: int, ip: int, itemsize: int,
     return itemsize * max(pre, post)
 
 
+# Share of the raised VMEM limit the scratch windows may take. The PRE
+# kernel's in-register temporaries need about as much again: compiled for
+# a v5e at 128³ (plane 136x256), block_k 17 (49.9 MiB of scratch) asked for
+# 107.8 MiB of scoped VMEM and was refused, block_k 12 (36.7 MiB) compiled
+# (tests/test_chip_compile.py keeps the 128³ compile).
+SCRATCH_SHARE = 3
+
+
 def pick_block_k_fused(kext: int, jp: int, ip: int, dtype,
                        masked: bool = False) -> int:
     """Block depth: budget the resident planes (20·bk + 12·h of the pre
-    kernel, +2·bk+4·h for the flag window) against half the raised VMEM
-    limit, capped by the whole grid."""
+    kernel, +2·bk+4·h for the flag window) against a third of the raised
+    VMEM limit (SCRATCH_SHARE), capped by the whole grid."""
     plane = jp * ip * jnp.dtype(dtype).itemsize
     h = FUSE_CHAIN
     per_bk = 22 if masked else 20
     per_h = 16 if masked else 12
-    feasible = ((VMEM_LIMIT_BYTES // 2) // plane - per_h * h) // per_bk
+    feasible = ((VMEM_LIMIT_BYTES // SCRATCH_SHARE) // plane
+                - per_h * h) // per_bk
     return max(1, min(feasible, kext, 32))
 
 
@@ -984,13 +993,8 @@ def probe_fused_3d() -> bool:
             out = post(offs, dt11, up, vp, wp, fp, gp, hp, z)
             float(out[3])  # force completion
             _PROBE_OK = True
-        except Exception:  # lint: allow(broad-except) — probe contract: any failure means "don't dispatch"
-            import warnings
+        except Exception as exc:  # lint: allow(broad-except) — probe contract: raise on TPU, report unavailable elsewhere
+            from ..utils.dispatch import probe_failed
 
-            warnings.warn(
-                "fused 3-D NS step-phase kernels unavailable; keeping the "
-                "jnp phase chain",
-                stacklevel=2,
-            )
-            _PROBE_OK = False
+            _PROBE_OK = probe_failed("the fused NS-3D step-phase kernels", exc)
     return _PROBE_OK

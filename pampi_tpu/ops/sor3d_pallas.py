@@ -829,7 +829,7 @@ _PROBE3D_OK: bool | None = None
 def probe_pallas_3d() -> bool:
     """One-time smoke test of the 3-D kernel on the real backend (same
     contract as sor_pallas.probe_pallas): chip/toolchain-wide failures
-    surface here once and the dispatcher falls back to jnp."""
+    surface here once, raised on a TPU backend."""
     global _PROBE3D_OK
     if _PROBE3D_OK is None:
         try:
@@ -841,13 +841,8 @@ def probe_pallas_3d() -> bool:
             _, res = rb(z, z)
             float(res)  # force completion: async errors surface here
             _PROBE3D_OK = True
-        except Exception as exc:  # lint: allow(broad-except) — probe contract: any failure means "don't dispatch"
-            import warnings
+        except Exception as exc:  # lint: allow(broad-except) — probe contract: raise on TPU, report unavailable elsewhere
+            from ..utils.dispatch import probe_failed
 
-            warnings.warn(
-                f"pallas 3-D TPU kernel unavailable ({type(exc).__name__}); "
-                "falling back to the jnp path",
-                stacklevel=2,
-            )
-            _PROBE3D_OK = False
+            _PROBE3D_OK = probe_failed("the 3-D SOR Pallas kernel", exc)
     return _PROBE3D_OK

@@ -180,23 +180,13 @@ def dims_create(nranks: int, ndims: int,
 
 
 def compat_shard_map(fn, mesh, in_specs, out_specs, check_vma: bool = True):
-    """`jax.shard_map` across toolchains — the ONE place the version shim
-    lives (CartComm.shard_map, models/dmvm.py and tests/test_sor_pallas.py
-    all route through it). Older jax only ships
-    `jax.experimental.shard_map`, whose check_rep predates the while-loop
-    replication rule every chunked solver needs, so validation is forced
-    off on that branch; the check_vma contract is still enforced wherever
-    `jax.shard_map` exists (the TPU image and the CI mesh tests there)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    """`jax.shard_map` with this repo's keyword spelling — the ONE call
+    site of it (CartComm.shard_map, models/dmvm.py and
+    tests/test_sor_pallas.py all route through here; astlint's
+    raw-shard-map rule keeps it that way)."""
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=check_vma,
     )
 
 
@@ -280,6 +270,17 @@ class CartComm:
     def shard(self, arr):
         """Place a global (interior-only) array sharded over the mesh."""
         return jax.device_put(arr, self.sharding())
+
+    def replicate(self, arr):
+        """Place an array replicated over the mesh — where the chunk's
+        P() outputs (loop time, step count, metrics) come back, so the
+        first call sees the shardings every later call does (placed on
+        one device, the overlapped 2x2 dcavity 4096² chunk compiled twice
+        on the chip, 35 s each). Built per addressable device, so it holds
+        under a multi-process mesh too."""
+        host = np.asarray(arr)
+        return jax.make_array_from_callback(
+            host.shape, NamedSharding(self.mesh, P()), lambda _idx: host)
 
     def local_shape(self, global_shape, ragged: bool = False) -> tuple[int, ...]:
         """Uniform per-shard block extents. ragged=False enforces the

@@ -116,11 +116,15 @@ def check_bands(grid_bands, block_rows: int, nblocks: int,
         last_end = s + n * block_rows
 
 
-def band_cover(lo: int, hi: int, block_rows: int, total_rows: int):
+def band_cover(lo: int, hi: int, block_rows: int, total_rows: int,
+               align: int = 1):
     """The (start_row, n_blocks) band of `block_rows`-row blocks that
     covers rows [lo, hi) and stays inside [0, total_rows): the start is
-    shifted down when the rounded-up coverage would overhang (extra
-    covered rows are valid compute — every write is globally gated)."""
+    rounded down to a multiple of `align` (a DMA row offset must sit on
+    the sublane tile) and shifted down when the rounded-up coverage would
+    overhang (extra covered rows are valid compute — every write is
+    globally gated)."""
+    lo = lo // align * align
     n = -(-(hi - lo) // block_rows)
     start = max(0, min(lo, total_rows - n * block_rows))
     return (start, n)
@@ -152,7 +156,7 @@ def _merge_bands(bands, block_rows, total_rows):
 
 
 def region_plan(local_extents, rim: int, ext_pad: int, block_rows: int,
-                nblocks: int, width: int, partitioned):
+                nblocks: int, width: int, partitioned, align: int = 1):
     """Banded grid plan for the two PRE halves of one shard geometry,
     over the LEADING (block-tiled) axis. Returns None when the interior
     region is empty (the split is boundary-everywhere — nothing to
@@ -169,7 +173,10 @@ def region_plan(local_extents, rim: int, ext_pad: int, block_rows: int,
     (`interior_slices` with the same `partitioned` flags — the mask and
     the grid cannot drift apart); the boundary band covers the rim rows,
     widened to every row when any non-leading axis is partitioned (its
-    column strips live in every row)."""
+    column strips live in every row). `align` rounds every band start down
+    to a multiple of it: the 2-D kernels DMA row windows from the band
+    start, and Mosaic refuses a row offset off the sublane tile (found
+    compiling the 2x2 dcavity 4096² chunk for a v5e)."""
     L0 = local_extents[0]
     R = nblocks * block_rows
     lead = partitioned[0]
@@ -180,13 +187,13 @@ def region_plan(local_extents, rim: int, ext_pad: int, block_rows: int,
     if int_hi <= int_lo:
         return None
     int_bands = _merge_bands(
-        [band_cover(int_lo, int_hi, block_rows, R)], block_rows, R)
+        [band_cover(int_lo, int_hi, block_rows, R, align)], block_rows, R)
     if cross:
-        bnd = [band_cover(ext_pad, ext_pad + L0 + 2, block_rows, R)]
+        bnd = [band_cover(ext_pad, ext_pad + L0 + 2, block_rows, R, align)]
     elif lead:
-        bnd = [band_cover(ext_pad, ext_pad + rim, block_rows, R),
+        bnd = [band_cover(ext_pad, ext_pad + rim, block_rows, R, align),
                band_cover(ext_pad + L0 + 2 - rim, ext_pad + L0 + 2,
-                          block_rows, R)]
+                          block_rows, R, align)]
     else:
         # no partitioned axis at all: no exchange, no overlap, no plan
         return None

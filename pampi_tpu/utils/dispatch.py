@@ -27,6 +27,33 @@ def snapshot() -> dict[str, str]:
     return dict(_RECORD)
 
 
+def reset() -> None:
+    """Forget every decision: a driver that builds several runs in one
+    process (chip_smoke.py) reads each run's snapshot on its own."""
+    _RECORD.clear()
+
+
+def probe_failed(what: str, exc: Exception) -> bool:
+    """The kernel-family probes' shared failure path (ops/sor_pallas,
+    sor3d_pallas, ns2d_fused, ns3d_fused, mg_fused). On a TPU backend a
+    family that does not compile or run is an error, raised with the
+    compiler's message: dropping every caller to the jnp chain would let
+    a run "succeed" on the chip with no Pallas kernel in it. Off-TPU (a
+    probe forced there) the family is reported unavailable: returns
+    False after a warning."""
+    import warnings
+
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{what} failed its on-chip probe: {type(exc).__name__}: {exc}"
+        ) from exc
+    warnings.warn(f"{what} unavailable ({type(exc).__name__}); the "
+                  "jnp path runs instead", stacklevel=3)
+    return False
+
+
 def resolve_solver(param, obstacles: bool, ragged: bool = False):
     """`tpu_solver auto` -> the measured-best solver for the run's
     structure (VERDICT r4 item 4: the solver-selection knowledge lived only
@@ -147,6 +174,11 @@ def resolve_mg_fused(knob: str, backend: str, dtype, key: str,
         return False
     if jnp.dtype(dtype).itemsize > 4:
         record(key, "jnp (dtype not Mosaic-lowerable)")
+        return False
+    from ..ops.mg_fused import TPU_BLOCKER
+
+    if TPU_BLOCKER:
+        record(key, f"jnp ({TPU_BLOCKER})")
         return False
     if probe is not None and not probe():
         record(key, "jnp (probe failed)")

@@ -83,7 +83,7 @@ def test_raw_shard_map_flagged(tmp_path):
     shard_map`, aliased module import). Applies to harness trees too
     (tools/ and tests/ regressed before)."""
     src = ("import jax\n"
-           "from jax.experimental.shard_map import shard_map\n"
+           "from jax._src.shard_map import shard_map\n"
            "f = jax.shard_map(lambda x: x, None, None, None)\n")
     vs = _lint_src(tmp_path, src, name="tools/seeded_tool.py")
     assert [v.line for v in vs] == [2, 3]
@@ -98,7 +98,7 @@ def test_raw_shard_map_flagged(tmp_path):
 
     # the newer-jax spelling and the aliased import both flag too
     src2 = ("from jax import shard_map\n"
-            "import jax.experimental.shard_map as sm\n"
+            "import jax._src.shard_map as sm\n"
             "a = shard_map(lambda x: x, None, None, None)\n"
             "b = sm.shard_map(lambda x: x, None, None, None)\n")
     vs = _lint_src(tmp_path, src2, name="tools/seeded_tool2.py")
@@ -153,7 +153,7 @@ def test_env_inventory_complete():
         ("PAMPI_FAULTS", "utils/faultinject.py"),
         ("PAMPI_PROFILE", "utils/profiling.py"),
         ("PAMPI_CSV", "models/dmvm.py"),
-        ("PAMPI_XLA_CACHE", "utils/xlacache.py"),
+        ("JAX_COMPILATION_CACHE_DIR", "utils/xlacache.py"),
         ("PAMPI_NATIVE", "utils/native.py"),
         ("PAMPI_COORDINATOR", "parallel/multihost.py"),
     ]:
@@ -357,7 +357,7 @@ def test_callback_and_dtype_detectors():
         return x * 2.0
 
     jx = jax.make_jaxpr(noisy)(1.0)
-    assert jaxprcheck.host_callbacks(jx.jaxpr) == ["debug_callback"]
+    assert jaxprcheck.host_callbacks(jx.jaxpr) == ["debug_print"]
 
     def promoting(x):
         return x.astype(jnp.float64) + 1.0, x * jnp.float32(2)

@@ -1,6 +1,6 @@
 """tools/bench_trend.py: the BENCH trend normalization + regression gate.
 
-The committed BENCH_r01-r06 series must render populated (the round-8
+A multi-round artifact series must render populated (the round-8
 bench-trend input parsed to [] — the normalized `metrics` schema exists
 so that never recurs) and regression-free; a synthetic injected
 regression must fail; CPU and TPU points must never gate against each
@@ -23,21 +23,20 @@ def _pt(value, name="m", unit="updates/s", backend="tpu"):
     return {"name": name, "value": value, "unit": unit, "backend": backend}
 
 
-def test_committed_artifacts_render_populated():
-    """The acceptance pin: the committed BENCH_r01-r06 set yields a
-    multi-round, backend-partitioned series — never [] — and carries no
-    regression at the default tolerance."""
-    files = bt.default_files()
-    assert len(files) >= 6
+def test_artifacts_render_populated(tmp_path):
+    """The acceptance pin, over synthetic artifacts: a multi-round tpu
+    series and a cpu series yield a backend-partitioned trend — never
+    [] — with no regression at the default tolerance."""
+    head = "lattice_site_updates_per_sec_per_chip_poisson4096_rbsor"
+    files = [_art(tmp_path, n, [_pt(v, name=head)])
+             for n, v in ((1, 1.5e11), (2, 1.51e11), (3, 1.58e11),
+                          (4, 1.59e11), (5, 1.59e11))]
+    files.append(_art(tmp_path, 6, [_pt(6.7e7, name=head, backend="cpu")]))
     series = bt.build_series(bt.load_points(files))
-    assert series, "committed BENCH artifacts yielded zero trend points"
-    # multi-round: the TPU poisson headline spans rounds 1-5
-    key = ("lattice_site_updates_per_sec_per_chip_poisson4096_rbsor", "tpu")
-    assert key in series and len(series[key]) >= 4
-    # backend partition: round 6 is the CPU growth container
-    assert ("lattice_site_updates_per_sec_per_chip_poisson4096_rbsor",
-            "cpu") in series
-    assert bt.lint() == []
+    assert series, "BENCH artifacts yielded zero trend points"
+    assert (head, "tpu") in series and len(series[(head, "tpu")]) >= 4
+    assert (head, "cpu") in series
+    assert bt.lint(files) == []
     table = bt.render(series)
     assert "r01" in table and "r06" in table and "[tpu]" in table
 

@@ -255,8 +255,19 @@ def test_coordinated_pallas_fallback_completes(faults, tel_on):
 # xlacache wedge hardening (satellite): dead cache path -> warn + uncached
 # ---------------------------------------------------------------------------
 
+@pytest.fixture
+def cache_switch_restored():
+    """The probe-failure path switches JAX's cache off process-wide."""
+    import jax
+
+    prev = jax.config.jax_enable_compilation_cache
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
 def test_xlacache_unusable_dir_proceeds_uncached(tmp_path, monkeypatch,
-                                                 tel_on):
+                                                 tel_on,
+                                                 cache_switch_restored):
     """A cache path that cannot be used (here: a FILE where the dir
     should be) degrades to warn-and-run-uncached with a structured
     telemetry `warning` record — never a blocked run."""
@@ -264,7 +275,7 @@ def test_xlacache_unusable_dir_proceeds_uncached(tmp_path, monkeypatch,
 
     bogus = tmp_path / "cachefile"
     bogus.write_text("not a directory")
-    monkeypatch.setenv("PAMPI_XLA_CACHE", str(bogus))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(bogus))
     with pytest.warns(UserWarning, match="UNCACHED"):
         assert xlacache.enable() is None
     warns = _records(tel_on, "warning")
@@ -277,7 +288,8 @@ def test_xlacache_unusable_dir_proceeds_uncached(tmp_path, monkeypatch,
     assert ca.lint_telemetry_summary(summ, "X") == []
 
 
-def test_xlacache_hung_probe_times_out(tmp_path, monkeypatch, tel_on):
+def test_xlacache_hung_probe_times_out(tmp_path, monkeypatch, tel_on,
+                                       cache_switch_restored):
     """The documented wedge (xlacache.py): storage that HANGS (a dead
     shared mount — os calls block forever) is bounded by the probe
     timeout; the run proceeds uncached instead of wedging the fleet."""
@@ -285,7 +297,7 @@ def test_xlacache_hung_probe_times_out(tmp_path, monkeypatch, tel_on):
 
     from pampi_tpu.utils import xlacache
 
-    monkeypatch.setenv("PAMPI_XLA_CACHE", str(tmp_path / "cache"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     monkeypatch.setenv("PAMPI_XLA_CACHE_TIMEOUT", "0.2")
     monkeypatch.setattr(xlacache.os, "makedirs",
                         lambda *a, **k: time.sleep(5))

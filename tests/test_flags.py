@@ -123,18 +123,41 @@ def test_xla_cache_enable_and_disable(monkeypatch, tmp_path):
     from pampi_tpu.utils import xlacache
 
     prev = jax.config.jax_compilation_cache_dir
+    tracebacks = jax.config.jax_include_full_tracebacks_in_locations
     try:
-        monkeypatch.setenv("PAMPI_XLA_CACHE", str(tmp_path / "c"))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
         assert xlacache.enable() == str(tmp_path / "c")
         assert (tmp_path / "c").is_dir()
         assert jax.config.jax_compilation_cache_dir == str(tmp_path / "c")
+        # one program, one cache key, whatever the call stack
+        assert not jax.config.jax_include_full_tracebacks_in_locations
         jax.config.update("jax_compilation_cache_dir", prev)
-        monkeypatch.setenv("PAMPI_XLA_CACHE", "0")
+        jax.config.update("jax_enable_compilation_cache", False)
         assert xlacache.enable() is None
         # disabled means the config was left untouched
         assert jax.config.jax_compilation_cache_dir == prev
     finally:
+        jax.config.update("jax_enable_compilation_cache", True)
         jax.config.update("jax_compilation_cache_dir", prev)
+        jax.config.update("jax_include_full_tracebacks_in_locations",
+                          tracebacks)
+
+
+def test_xla_cache_dir_unset_resolves_to_checkout(monkeypatch):
+    """Unset JAX_COMPILATION_CACHE_DIR: an accelerator run caches in the
+    fixed <checkout>/.jax_cache (never a home, tmp or pid path) and a
+    CPU run stays uncached."""
+    import os
+
+    from pampi_tpu.utils import xlacache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert xlacache.cache_dir("tpu") == os.path.join(repo, ".jax_cache")
+    assert xlacache.cache_dir("cpu") is None
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert xlacache.cache_dir("tpu") == xlacache.cache_dir("cpu") \
+        == "/some/dir"
 
 
 DCAVITY3D_PAR = """\

@@ -136,6 +136,20 @@ def test_knob_off_is_the_historical_program():
         str(jax.make_jaxpr(off)(p0, rhs))
 
 
+def test_tpu_auto_keeps_ladder_with_blocker(monkeypatch):
+    """The fused cycle does not lower for a TPU (ops/mg_fused.TPU_BLOCKER;
+    tests/test_chip_compile.py pins the refusal): `auto` on a TPU keeps
+    the ladder and says why, without calling the probe."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def probe():
+        raise AssertionError("the blocked family must not be probed")
+
+    assert not disp.resolve_mg_fused("auto", "auto", jnp.float32,
+                                     "mg2d_fused", probe=probe)
+    assert disp.last("mg2d_fused").startswith("jnp (fused cycle not")
+
+
 def test_ragged_single_level_refuses_with_reason():
     """A 33² grid is a single-level plan: the knob forced on must fall
     back to the jnp ladder AND say why in the dispatch record."""

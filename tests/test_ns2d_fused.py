@@ -463,6 +463,37 @@ def test_retry_backend_disables_fusion():
     assert dispatch.last("ns2d_phases") == "jnp (retry fallback backend)"
 
 
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_probe_failure_raises_on_tpu(monkeypatch, backend):
+    """A kernel family whose probe fails is an error on a TPU (the
+    compiler's message raised), and a reported-unavailable family
+    elsewhere — never a silent drop to the jnp chain on the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    exc = ValueError("Mosaic says no")
+    if backend == "tpu":
+        with pytest.raises(RuntimeError, match="Mosaic says no"):
+            dispatch.probe_failed("the kernel", exc)
+    else:
+        with pytest.warns(UserWarning, match="unavailable"):
+            assert dispatch.probe_failed("the kernel", exc) is False
+
+
+@pytest.mark.parametrize("backend,want", [
+    ("auto", "jnp (no TPU)"),
+    ("jnp", "jnp (retry fallback backend)"),
+    ("pallas", "pallas_quarters (n_inner=2)"),
+])
+def test_sor2d_dispatch_recorded(backend, want):
+    """The 2-D SOR backend decision lands in the snapshot the chip smoke
+    checks (`sor2d`), with the reason on the jnp side."""
+    from pampi_tpu.models.poisson import make_rb_loop
+
+    dispatch.reset()
+    make_rb_loop(16, 16, 1 / 16, 1 / 16, 1.7, jnp.float32,
+                 backend=backend, n_inner=2)
+    assert dispatch.snapshot() == {"sor2d": want}
+
+
 def test_fuse_knob_validation():
     with pytest.raises(ValueError, match="tpu_fuse_phases"):
         NS2DSolver(Parameter(name="dcavity", imax=16, jmax=16,

@@ -21,7 +21,7 @@ from pampi_tpu.utils.params import Parameter
 
 N = int(os.environ.get("VAR_N", 4096))
 TOTAL = int(os.environ.get("VAR_TOTAL", 96))  # one dispatch; raise to
-# amortize a high tunnel latency floor
+# amortize a high per-dispatch latency floor
 K = int(os.environ.get("VAR_K", 4))
 BR = int(os.environ.get("VAR_BR", 256))
 
