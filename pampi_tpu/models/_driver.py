@@ -36,6 +36,7 @@ from collections import deque
 import jax
 
 from ..utils import faultinject as _fi
+from ..utils import profiling as _prof
 from ..utils import telemetry as _tm
 
 
@@ -122,7 +123,13 @@ def drive_chunks(state, chunk_fn, te, time_index, bar, retry, on_state=None,
     utils/checkpoint.load_elastic): the spent transient budget carries
     over so a resumed run starts with the charge it died with — the
     rank-symmetric no-amnesia contract (the pallas verdict and dt clamp
-    were already re-applied at load time)."""
+    were already re-applied at load time).
+
+    Every chunk is three host spans (utils/profiling.region, in any active
+    profiler trace): `drive.dispatch` around the chunk call, `drive.wait`
+    around the fence that reads its loop time, `drive.sync` around the
+    bookkeeping after a confirmation (restore hook, bar, ETA, on_state).
+    The coordinated loop uses the same three names."""
     if coordinator is not None:
         from ..parallel.coordinator import drive_coordinated
 
@@ -152,7 +159,6 @@ def drive_chunks(state, chunk_fn, te, time_index, bar, retry, on_state=None,
     # scalar readback per chunk — and only when the flag is armed, on
     # process 0 only (the master-only emitter convention; N ranks
     # \r-redrawing one terminal would garble it).
-    from ..utils import profiling as _prof
     from ..utils.progress import ChunkEta
 
     eta = (ChunkEta(te)
@@ -171,13 +177,15 @@ def drive_chunks(state, chunk_fn, te, time_index, bar, retry, on_state=None,
         try:
             if len(pending) <= lookahead:
                 _fi.maybe_chunk_fault()  # injected fault plane (test-only)
-                newest = chunk_fn(*newest)
+                with _prof.region("drive.dispatch"):
+                    newest = chunk_fn(*newest)
                 pending.append(newest)
                 continue
             old = pending.popleft()
             # force completion of the oldest in-flight chunk: async pallas
             # faults surface here, overlapped with the younger dispatches
-            t_old = float(old[time_index])
+            with _prof.region("drive.wait"):
+                t_old = float(old[time_index])
         except Exception as exc:  # lint: allow(broad-except) — the fault-classification funnel: every runtime error class routes to transient/pallas/raise below
             if isinstance(exc, _fi.FaultSpecError):
                 raise  # a broken TEST spec fails loudly at the first hook
@@ -217,30 +225,32 @@ def drive_chunks(state, chunk_fn, te, time_index, bar, retry, on_state=None,
                 raise
             chunk_fn = new_fn
             continue
-        confirmed = old
-        # a diverged chunk is NOT clean: judge it before the replenish /
-        # restore accounting so a poisoned confirmation can neither refill
-        # the transient budget nor trigger a pallas restore
-        diverged = t_old != t_old or (
-            recover is not None and recover.poisoned(old)
-        )
-        if not diverged:
-            clean += 1
-            if (replenish_after > 0 and clean >= replenish_after
-                    and transient_budget < max_transient):
-                transient_budget = max_transient  # M clean chunks: refill
-            restore = getattr(retry, "on_clean_chunk", None)
-            if restore is not None:
-                restored_fn = restore()
-                if restored_fn is not None:
-                    # in-flight jnp states stay valid — only future
-                    # dispatches run the restored pallas chunk
-                    chunk_fn = restored_fn
-        bar.update(t_old)
-        if eta is not None:
-            eta.update(t_old, int(old[time_index + 1]))
-        if on_state is not None:
-            on_state(old)
+        # the host's bookkeeping after a confirmation, one span per chunk
+        with _prof.region("drive.sync"):
+            confirmed = old
+            # a diverged chunk is NOT clean: judge it before the replenish /
+            # restore accounting so a poisoned confirmation can neither refill
+            # the transient budget nor trigger a pallas restore
+            diverged = t_old != t_old or (
+                recover is not None and recover.poisoned(old)
+            )
+            if not diverged:
+                clean += 1
+                if (replenish_after > 0 and clean >= replenish_after
+                        and transient_budget < max_transient):
+                    transient_budget = max_transient  # M clean chunks: refill
+                restore = getattr(retry, "on_clean_chunk", None)
+                if restore is not None:
+                    restored_fn = restore()
+                    if restored_fn is not None:
+                        # in-flight jnp states stay valid — only future
+                        # dispatches run the restored pallas chunk
+                        chunk_fn = restored_fn
+            bar.update(t_old)
+            if eta is not None:
+                eta.update(t_old, int(old[time_index + 1]))
+            if on_state is not None:
+                on_state(old)
         # NaN loop time is terminal, not "not yet past te": an adaptive-dt
         # blow-up makes dt and then t NaN, every subsequent chunk is a
         # device no-op (its while-cond sees NaN <= te false), and

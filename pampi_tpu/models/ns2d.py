@@ -31,6 +31,7 @@ from jax import lax
 from ..ops import ns2d as ops
 from ..utils import faultinject as _fi
 from ..utils import flags as _flags
+from ..utils import profiling as _prof
 from ..utils import telemetry as _tm
 from ._driver import clamped_dt
 from ..utils.datio import write_pressure, write_velocity
@@ -219,7 +220,7 @@ class NS2DSolver:
                 masks, dtype, backend=backend,
                 n_inner=param.tpu_sor_inner,
             )
-        return solve
+        return _prof.scoped("solve", solve)
 
     # -- one full timestep, traced ------------------------------------
     def _build_presolve(self):
@@ -316,34 +317,39 @@ class NS2DSolver:
         faults = getattr(self, "_field_faults", ())
 
         def step(u, v, p, t, nt):
-            u, v, p = _fi.apply_field_faults(faults, nt, u=u, v=v, p=p)
-            u, v, f, g, rhs, dt = presolve(u, v)
-            if masks is None:
-                p = lax.cond(nt % 100 == 0, ops.normalize_pressure, lambda q: q, p)
-            else:
-                from ..ops.obstacle import normalize_pressure_fluid
+            with jax.named_scope("phases"):
+                u, v, p = _fi.apply_field_faults(faults, nt, u=u, v=v, p=p)
+                u, v, f, g, rhs, dt = presolve(u, v)
+                if masks is None:
+                    p = lax.cond(nt % 100 == 0, ops.normalize_pressure,
+                                 lambda q: q, p)
+                else:
+                    from ..ops.obstacle import normalize_pressure_fluid
 
-                p = lax.cond(
-                    nt % 100 == 0,
-                    lambda q: normalize_pressure_fluid(q, masks),
-                    lambda q: q,
-                    p,
-                )
+                    p = lax.cond(
+                        nt % 100 == 0,
+                        lambda q: normalize_pressure_fluid(q, masks),
+                        lambda q: q,
+                        p,
+                    )
             p, res, it = solve(p, rhs)
-            if masks is None:
-                u, v = ops.adapt_uv(u, v, f, g, p, dt, dx, dy)
-            else:
-                from ..ops.obstacle import adapt_uv_obstacle
+            with jax.named_scope("phases"):
+                if masks is None:
+                    u, v = ops.adapt_uv(u, v, f, g, p, dt, dx, dy)
+                else:
+                    from ..ops.obstacle import adapt_uv_obstacle
 
-                u, v = adapt_uv_obstacle(u, v, f, g, p, dt, dx, dy, masks)
-            # t accumulates in high precision regardless of the field dtype
-            # (bfloat16 would stall t once ulp/2 > dt and never reach te)
-            time_dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-            t_next = t + dt.astype(time_dtype)
-            if _flags.verbose():
-                # ≙ -DVERBOSE "TIME %f , TIMESTEP %f" printed AFTER t += dt
-                # (A5 main.c:52-57)
-                jax.debug.print("TIME {} , TIMESTEP {}", t_next, dt)
+                    u, v = adapt_uv_obstacle(u, v, f, g, p, dt, dx, dy, masks)
+                # t accumulates in high precision regardless of the field
+                # dtype (bfloat16 would stall t once ulp/2 > dt and never
+                # reach te)
+                time_dtype = (jnp.float64 if jax.config.jax_enable_x64
+                              else jnp.float32)
+                t_next = t + dt.astype(time_dtype)
+                if _flags.verbose():
+                    # ≙ -DVERBOSE "TIME %f , TIMESTEP %f" printed AFTER
+                    # t += dt (A5 main.c:52-57)
+                    jax.debug.print("TIME {} , TIMESTEP {}", t_next, dt)
             if instrumented:
                 return u, v, p, t_next, nt + 1, res, it, dt
             return u, v, p, t_next, nt + 1
@@ -463,7 +469,8 @@ class NS2DSolver:
         if solve_pad is not None:
             record("sor2d", f"pallas_tblock (n_inner={param.tpu_sor_inner}"
                             ", folded)")
-        solve = self._make_solve(backend) if solve_pad is None else solve_pad
+        solve = (self._make_solve(backend) if solve_pad is None
+                 else _prof.scoped("solve", solve_pad))
         if solve_pad is not None:
             # time_solve_ms must time THIS padded-layout solve, not the
             # conversion-wrapped _make_solve the folded step no longer runs
@@ -493,26 +500,27 @@ class NS2DSolver:
             norm_carry = normalize
 
         def step(up, vp, p, t, nt, umax, vmax):
-            # `p` is the padded carry when folded, the plain array otherwise
-            up, vp, p = _fi.apply_field_faults(faults, nt, u=up, v=vp, p=p)
-            if adaptive:
-                dt = ops.cfl_dt(umax, vmax, self.dt_bound, dx, dy, param.tau)
-            else:
-                dt = jnp.asarray(param.dt, dtype)
-            dt = clamped_dt(dt, dt_scale)
-            dt11 = jnp.full((1, 1), dt, dtype)
-            up, vp, fp, gp, rhsp = pre(offs, dt11, up, vp)
-            p = lax.cond(nt % 100 == 0, norm_carry, lambda q: q, p)
-            if folded:
-                p, _res, _it = solve(p, rhsp)
-                p_post = p
-            else:
-                p, _res, _it = solve(p, unpad(rhsp))
-                p_post = pad(p)
-            up, vp, umax, vmax = post(offs, dt11, up, vp, fp, gp, p_post)
-            t_next = t + dt.astype(time_dtype)
-            if _flags.verbose():
-                jax.debug.print("TIME {} , TIMESTEP {}", t_next, dt)
+            with jax.named_scope("phases"):
+                # `p` is the padded carry when folded, the plain array
+                # otherwise
+                up, vp, p = _fi.apply_field_faults(faults, nt, u=up, v=vp, p=p)
+                if adaptive:
+                    dt = ops.cfl_dt(umax, vmax, self.dt_bound, dx, dy,
+                                    param.tau)
+                else:
+                    dt = jnp.asarray(param.dt, dtype)
+                dt = clamped_dt(dt, dt_scale)
+                dt11 = jnp.full((1, 1), dt, dtype)
+                up, vp, fp, gp, rhsp = pre(offs, dt11, up, vp)
+                p = lax.cond(nt % 100 == 0, norm_carry, lambda q: q, p)
+                rhs = rhsp if folded else unpad(rhsp)
+            p, _res, _it = solve(p, rhs)
+            with jax.named_scope("phases"):
+                p_post = p if folded else pad(p)
+                up, vp, umax, vmax = post(offs, dt11, up, vp, fp, gp, p_post)
+                t_next = t + dt.astype(time_dtype)
+                if _flags.verbose():
+                    jax.debug.print("TIME {} , TIMESTEP {}", t_next, dt)
             if metrics:
                 return (up, vp, p, t_next, nt + 1, umax, vmax,
                         _res, _it, dt)

@@ -70,6 +70,7 @@ from ..parallel.stencil2d import (
 from ..utils import dispatch as _dispatch
 from ..utils import faultinject as _fi
 from ..utils import flags as _flags
+from ..utils import profiling as _prof
 from ..utils import telemetry as _tm
 from ..utils import xprof as _xprof
 from ._driver import clamped_dt
@@ -622,6 +623,9 @@ class NS2DDistSolver:
         adapt_n = int(param.tpu_itermax_adaptive)
         use_cap = adapt_n > 0 and solve in (
             _solve_sor, _solve_sor_split, _solve_sor_quarters)
+        # the solve (its layout pack/unpack and exchanges included) under
+        # one named scope, the rest of the step under "phases"
+        solve = _prof.scoped("solve", solve)
         if adapt_n > 0:
             _dispatch.record(
                 "itermax_adaptive_ns2d_dist",
@@ -738,32 +742,33 @@ class NS2DDistSolver:
             intermediates (the automated heir of the reference's test.c
             halo dump, SURVEY.md §4.1). `cap` is the residual-adaptive
             sweep budget (None = the historical static-itermax trace)."""
-            u, v, p = _fi.apply_field_faults(field_faults, nt, u=u, v=v, p=p)
-            u = halo_exchange(u, comm)
-            v = halo_exchange(v, comm)
-            dt = compute_dt(u, v) if adaptive else jnp.asarray(param.dt, dtype)
-            dt = clamped_dt(dt, dt_scale)
-            u, v = set_bcs(u, v)
-            u = set_special_bc(u)
-            u = halo_exchange(u, comm)
-            v = halo_exchange(v, comm)
-            if gmasks is not None:
-                # needs the fully-exchanged post-BC state (the single-device
-                # op reads the whole array at once); its own halo-cell
-                # outputs are refreshed by one more exchange
-                u, v = apply_obstacle_velocity_bc(u, v, local_masks())
+            with jax.named_scope("phases"):
+                u, v, p = _fi.apply_field_faults(field_faults, nt, u=u, v=v, p=p)
                 u = halo_exchange(u, comm)
                 v = halo_exchange(v, comm)
-            f, g = ops.compute_fg_interior(
-                u, v, dt, param.re, param.gx, param.gy, param.gamma, dx, dy
-            )
-            f, g = fg_fixups(f, g, u, v)
-            if gmasks is not None:
-                f, g = mask_fg(f, g, u, v, local_masks())
-            f = halo_shift(f, comm, "i")
-            g = halo_shift(g, comm, "j")
-            rhs = ops.compute_rhs(f, g, dt, dx, dy)
-            p = lax.cond(nt % 100 == 0, normalize_pressure, lambda q: q, p)
+                dt = compute_dt(u, v) if adaptive else jnp.asarray(param.dt, dtype)
+                dt = clamped_dt(dt, dt_scale)
+                u, v = set_bcs(u, v)
+                u = set_special_bc(u)
+                u = halo_exchange(u, comm)
+                v = halo_exchange(v, comm)
+                if gmasks is not None:
+                    # needs the fully-exchanged post-BC state (the single-device
+                    # op reads the whole array at once); its own halo-cell
+                    # outputs are refreshed by one more exchange
+                    u, v = apply_obstacle_velocity_bc(u, v, local_masks())
+                    u = halo_exchange(u, comm)
+                    v = halo_exchange(v, comm)
+                f, g = ops.compute_fg_interior(
+                    u, v, dt, param.re, param.gx, param.gy, param.gamma, dx, dy
+                )
+                f, g = fg_fixups(f, g, u, v)
+                if gmasks is not None:
+                    f, g = mask_fg(f, g, u, v, local_masks())
+                f = halo_shift(f, comm, "i")
+                g = halo_shift(g, comm, "j")
+                rhs = ops.compute_rhs(f, g, dt, dx, dy)
+                p = lax.cond(nt % 100 == 0, normalize_pressure, lambda q: q, p)
             p, res, it = (solve(p, rhs, cap) if cap is not None
                           else solve(p, rhs))
             return u, v, f, g, rhs, p, dt, res, it
@@ -772,49 +777,50 @@ class NS2DDistSolver:
             u, v, f, g, _rhs, p, dt, res, it = step_phases(u, v, p, nt,
                                                            cap)
 
-            def adapt(u, v):
-                if gmasks is not None:
-                    return adapt_uv_obstacle(
-                        u, v, f, g, p, dt, dx, dy, local_masks()
+            with jax.named_scope("phases"):
+                def adapt(u, v):
+                    if gmasks is not None:
+                        return adapt_uv_obstacle(
+                            u, v, f, g, p, dt, dx, dy, local_masks()
+                        )
+                    return ops.adapt_uv(u, v, f, g, p, dt, dx, dy)
+
+                if not self.ragged:
+                    u, v = adapt(u, v)
+                else:
+                    # ragged projection: update ONLY the true global interior.
+                    # The single-device adapt never touches ghost rows, but here
+                    # the global ghost ring can be interior-stored — clobbering
+                    # it would change what next step's ghost-inclusive CFL scan
+                    # (maxElement quirk) sees; dead cells are zeroed so halo
+                    # garbage cannot reach that scan either. One gating block
+                    # for the plain AND obstacle projections — the discipline
+                    # cannot drift between them.
+                    from ..parallel import ragged2d as rg
+
+                    gj, gi = rg.global_index_vectors(comm, jl, il)
+                    interior = (
+                        (gj >= 1) & (gj <= self.jmax)
+                        & (gi >= 1) & (gi <= self.imax)
                     )
-                return ops.adapt_uv(u, v, f, g, p, dt, dx, dy)
-
-            if not self.ragged:
-                u, v = adapt(u, v)
-            else:
-                # ragged projection: update ONLY the true global interior.
-                # The single-device adapt never touches ghost rows, but here
-                # the global ghost ring can be interior-stored — clobbering
-                # it would change what next step's ghost-inclusive CFL scan
-                # (maxElement quirk) sees; dead cells are zeroed so halo
-                # garbage cannot reach that scan either. One gating block
-                # for the plain AND obstacle projections — the discipline
-                # cannot drift between them.
-                from ..parallel import ragged2d as rg
-
-                gj, gi = rg.global_index_vectors(comm, jl, il)
-                interior = (
-                    (gj >= 1) & (gj <= self.jmax)
-                    & (gi >= 1) & (gi <= self.imax)
-                )
-                live = rg.live_masks(comm, jl, il, self.jmax, self.imax, dtype)
-                ua, va = adapt(u, v)
-                u = jnp.where(interior, ua, u) * live
-                v = jnp.where(interior, va, v) * live
-            # t accumulates in high precision regardless of the field dtype
-            # (bfloat16 would stall t once ulp/2 > dt and never reach te)
-            t_next = t + dt.astype(idx_dtype)
-            if _flags.verbose():
-                # printed AFTER t += dt, matching A5 main.c:52-57
-                master_print(comm, "TIME {} , TIMESTEP {}", t_next, dt)
-            capt = (next_cap(res, it),) if cap is not None else ()
-            if metrics:
-                # mesh-global |u|/|v| maxima (replicated, like res) — the
-                # in-band telemetry scalars; Allreduce MAX only on this path
-                um = reduction(jnp.max(jnp.abs(u)), comm, "max")
-                vm = reduction(jnp.max(jnp.abs(v)), comm, "max")
-                return (u, v, p, t_next, nt + 1, res, it, dt, um, vm) + capt
-            return (u, v, p, t_next, nt + 1) + capt
+                    live = rg.live_masks(comm, jl, il, self.jmax, self.imax, dtype)
+                    ua, va = adapt(u, v)
+                    u = jnp.where(interior, ua, u) * live
+                    v = jnp.where(interior, va, v) * live
+                # t accumulates in high precision regardless of the field dtype
+                # (bfloat16 would stall t once ulp/2 > dt and never reach te)
+                t_next = t + dt.astype(idx_dtype)
+                if _flags.verbose():
+                    # printed AFTER t += dt, matching A5 main.c:52-57
+                    master_print(comm, "TIME {} , TIMESTEP {}", t_next, dt)
+                capt = (next_cap(res, it),) if cap is not None else ()
+                if metrics:
+                    # mesh-global |u|/|v| maxima (replicated, like res) — the
+                    # in-band telemetry scalars; Allreduce MAX only on this path
+                    um = reduction(jnp.max(jnp.abs(u)), comm, "max")
+                    vm = reduction(jnp.max(jnp.abs(v)), comm, "max")
+                    return (u, v, p, t_next, nt + 1, res, it, dt, um, vm) + capt
+                return (u, v, p, t_next, nt + 1) + capt
 
         def step_fused(u, v, p, t, nt, cap=None, strips=None):
             """The fused-phase twin of step(): one deep exchange feeds the
@@ -826,63 +832,65 @@ class NS2DDistSolver:
             captured exchange (parallel/comm.paste_axis_strips) instead
             of a fresh per-step collective — relaxed parity, staleness
             bounded by the depth block."""
-            pre_k, post_k = fused_k
-            H = FUSE_DEEP_HALO
-            u, v, p = _fi.apply_field_faults(field_faults, nt, u=u, v=v, p=p)
-            if strips is None:
-                ud = halo_exchange(embed_deep(u, H), comm, depth=H)
-                vd = halo_exchange(embed_deep(v, H), comm, depth=H)
-            else:
-                from ..parallel.comm import paste_axis_strips
+            with jax.named_scope("phases"):
+                pre_k, post_k = fused_k
+                H = FUSE_DEEP_HALO
+                u, v, p = _fi.apply_field_faults(field_faults, nt, u=u, v=v, p=p)
+                if strips is None:
+                    ud = halo_exchange(embed_deep(u, H), comm, depth=H)
+                    vd = halo_exchange(embed_deep(v, H), comm, depth=H)
+                else:
+                    from ..parallel.comm import paste_axis_strips
 
-                (lo_u, hi_u), (lo_v, hi_v) = strips
-                ud = paste_axis_strips(
-                    embed_deep(u, H), comm, dax, H, lo_u, hi_u)
-                vd = paste_axis_strips(
-                    embed_deep(v, H), comm, dax, H, lo_v, hi_v)
-            # ghost-inclusive CFL max: the deep block carries the same
-            # global value set (owned + fresh neighbour copies + wall
-            # ghosts + dead zeros), so the max reduction is unchanged
-            dt = compute_dt(ud, vd) if adaptive else jnp.asarray(param.dt, dtype)
-            dt = clamped_dt(dt, dt_scale)
-            joff = get_offsets("j", jl)
-            ioff = get_offsets("i", il)
-            offs = jnp.stack([joff, ioff]).astype(jnp.int32)
-            dt11 = jnp.full((1, 1), dt, dtype)
-            pre_extra = post_extra = ()
-            if gmasks is not None:
-                flg_deep, flg_ext = fused_flag_blocks()
-                pre_extra = (flg_deep,)
-                post_extra = (flg_ext,)
-            upd, vpd, fpd, gpd, rpd = pre_k(
-                offs, dt11, pad_deep(ud), pad_deep(vd), *pre_extra
-            )
-            u = strip_deep(unpad_deep(upd), H)
-            v = strip_deep(unpad_deep(vpd), H)
-            f = strip_deep(unpad_deep(fpd), H)
-            g = strip_deep(unpad_deep(gpd), H)
-            rhs = strip_deep(unpad_deep(rpd), H)
-            p = lax.cond(nt % 100 == 0, normalize_pressure, lambda q: q, p)
+                    (lo_u, hi_u), (lo_v, hi_v) = strips
+                    ud = paste_axis_strips(
+                        embed_deep(u, H), comm, dax, H, lo_u, hi_u)
+                    vd = paste_axis_strips(
+                        embed_deep(v, H), comm, dax, H, lo_v, hi_v)
+                # ghost-inclusive CFL max: the deep block carries the same
+                # global value set (owned + fresh neighbour copies + wall
+                # ghosts + dead zeros), so the max reduction is unchanged
+                dt = compute_dt(ud, vd) if adaptive else jnp.asarray(param.dt, dtype)
+                dt = clamped_dt(dt, dt_scale)
+                joff = get_offsets("j", jl)
+                ioff = get_offsets("i", il)
+                offs = jnp.stack([joff, ioff]).astype(jnp.int32)
+                dt11 = jnp.full((1, 1), dt, dtype)
+                pre_extra = post_extra = ()
+                if gmasks is not None:
+                    flg_deep, flg_ext = fused_flag_blocks()
+                    pre_extra = (flg_deep,)
+                    post_extra = (flg_ext,)
+                upd, vpd, fpd, gpd, rpd = pre_k(
+                    offs, dt11, pad_deep(ud), pad_deep(vd), *pre_extra
+                )
+                u = strip_deep(unpad_deep(upd), H)
+                v = strip_deep(unpad_deep(vpd), H)
+                f = strip_deep(unpad_deep(fpd), H)
+                g = strip_deep(unpad_deep(gpd), H)
+                rhs = strip_deep(unpad_deep(rpd), H)
+                p = lax.cond(nt % 100 == 0, normalize_pressure, lambda q: q, p)
             p, _res, _it = (solve(p, rhs, cap) if cap is not None
                             else solve(p, rhs))
-            up, vp, um_l, vm_l = post_k(
-                offs, dt11, pad_ext(u), pad_ext(v), pad_ext(f), pad_ext(g),
-                pad_ext(p), *post_extra,
-            )
-            u = unpad_ext(up)
-            v = unpad_ext(vp)
-            t_next = t + dt.astype(idx_dtype)
-            if _flags.verbose():
-                master_print(comm, "TIME {} , TIMESTEP {}", t_next, dt)
-            capt = (next_cap(_res, _it),) if cap is not None else ()
-            if metrics:
-                # the POST kernel's carried maxima are per-shard: one
-                # Allreduce MAX makes them the global telemetry scalars
-                um = reduction(um_l, comm, "max")
-                vm = reduction(vm_l, comm, "max")
-                return (u, v, p, t_next, nt + 1, _res, _it, dt,
-                        um, vm) + capt
-            return (u, v, p, t_next, nt + 1) + capt
+            with jax.named_scope("phases"):
+                up, vp, um_l, vm_l = post_k(
+                    offs, dt11, pad_ext(u), pad_ext(v), pad_ext(f), pad_ext(g),
+                    pad_ext(p), *post_extra,
+                )
+                u = unpad_ext(up)
+                v = unpad_ext(vp)
+                t_next = t + dt.astype(idx_dtype)
+                if _flags.verbose():
+                    master_print(comm, "TIME {} , TIMESTEP {}", t_next, dt)
+                capt = (next_cap(_res, _it),) if cap is not None else ()
+                if metrics:
+                    # the POST kernel's carried maxima are per-shard: one
+                    # Allreduce MAX makes them the global telemetry scalars
+                    um = reduction(um_l, comm, "max")
+                    vm = reduction(vm_l, comm, "max")
+                    return (u, v, p, t_next, nt + 1, _res, _it, dt,
+                            um, vm) + capt
+                return (u, v, p, t_next, nt + 1) + capt
 
         if overlap:
             # -- overlapped fused step (parallel/overlap.py): the deep
@@ -952,61 +960,63 @@ class NS2DDistSolver:
 
             def step_overlap(u, v, p, t, nt, ud, vd, um, vm, gen,
                              cap=None):
-                pre_k, post_k = fused_k
-                # the restricted halves (when dispatched) are the SAME
-                # kernel on banded grids; values inside each band are
-                # bitwise the full sweep's (globally gated writes), and
-                # the merge mask selects only band-covered cells
-                pre_i = pre_int if pre_int is not None else pre_k
-                pre_b = pre_bnd if pre_bnd is not None else pre_k
-                dt = (cfl_from_maxima(um, vm) if adaptive
-                      else jnp.asarray(param.dt, dtype))
-                # stale-buffer detector: a generation-skewed double
-                # buffer poisons dt (NaN t -> drive-loop divergence)
-                dt = _ovl.generation_guard(dt, gen, nt)
-                dt = clamped_dt(dt, dt_scale)
-                joff = get_offsets("j", jl)
-                ioff = get_offsets("i", il)
-                offs = jnp.stack([joff, ioff]).astype(jnp.int32)
-                dt11 = jnp.full((1, 1), dt, dtype)
-                pre_extra = post_extra = ()
-                if gmasks is not None:
-                    flg_deep, flg_ext = fused_flag_blocks()
-                    pre_extra = (flg_deep,)
-                    post_extra = (flg_ext,)
-                ints = pre_i(offs, dt11, pad_deep(embed_deep(u, H)),
-                             pad_deep(embed_deep(v, H)), *pre_extra)
-                bnds = pre_b(offs, dt11, pad_deep(ud), pad_deep(vd),
-                             *pre_extra)
-                u, v, f, g, rhs = _ovl.merge_halves(
-                    int_mask,
-                    [strip_deep(unpad_deep(a), H) for a in ints],
-                    [strip_deep(unpad_deep(b), H) for b in bnds])
-                p = lax.cond(nt % 100 == 0, normalize_pressure,
-                             lambda q: q, p)
+                with jax.named_scope("phases"):
+                    pre_k, post_k = fused_k
+                    # the restricted halves (when dispatched) are the SAME
+                    # kernel on banded grids; values inside each band are
+                    # bitwise the full sweep's (globally gated writes), and
+                    # the merge mask selects only band-covered cells
+                    pre_i = pre_int if pre_int is not None else pre_k
+                    pre_b = pre_bnd if pre_bnd is not None else pre_k
+                    dt = (cfl_from_maxima(um, vm) if adaptive
+                          else jnp.asarray(param.dt, dtype))
+                    # stale-buffer detector: a generation-skewed double
+                    # buffer poisons dt (NaN t -> drive-loop divergence)
+                    dt = _ovl.generation_guard(dt, gen, nt)
+                    dt = clamped_dt(dt, dt_scale)
+                    joff = get_offsets("j", jl)
+                    ioff = get_offsets("i", il)
+                    offs = jnp.stack([joff, ioff]).astype(jnp.int32)
+                    dt11 = jnp.full((1, 1), dt, dtype)
+                    pre_extra = post_extra = ()
+                    if gmasks is not None:
+                        flg_deep, flg_ext = fused_flag_blocks()
+                        pre_extra = (flg_deep,)
+                        post_extra = (flg_ext,)
+                    ints = pre_i(offs, dt11, pad_deep(embed_deep(u, H)),
+                                 pad_deep(embed_deep(v, H)), *pre_extra)
+                    bnds = pre_b(offs, dt11, pad_deep(ud), pad_deep(vd),
+                                 *pre_extra)
+                    u, v, f, g, rhs = _ovl.merge_halves(
+                        int_mask,
+                        [strip_deep(unpad_deep(a), H) for a in ints],
+                        [strip_deep(unpad_deep(b), H) for b in bnds])
+                    p = lax.cond(nt % 100 == 0, normalize_pressure,
+                                 lambda q: q, p)
                 p, _res, _it = (solve(p, rhs, cap) if cap is not None
                                 else solve(p, rhs))
-                up, vp, um_l, vm_l = post_k(
-                    offs, dt11, pad_ext(u), pad_ext(v), pad_ext(f),
-                    pad_ext(g), pad_ext(p), *post_extra,
-                )
-                u = unpad_ext(up)
-                v = unpad_ext(vp)
-                # next step's CFL maxima: POST's carried per-shard maxima
-                # over the valid extended cells — the same global value
-                # set the serial step's exchanged-block scan sees
-                um = reduction(um_l, comm, "max")
-                vm = reduction(vm_l, comm, "max")
-                # post step N+1's exchange NOW: its results feed only the
-                # carried buffers (the boundary half, one iteration
-                # later) — nothing else in the trace depends on them
-                ud, vd = exchange_buffers(u, v)
-                t_next = t + dt.astype(idx_dtype)
-                if _flags.verbose():
-                    master_print(comm, "TIME {} , TIMESTEP {}", t_next, dt)
-                capt = (next_cap(_res, _it),) if cap is not None else ()
-                return (u, v, p, t_next, nt + 1, ud, vd, um, vm, nt + 1,
-                        _res, _it, dt) + capt
+                with jax.named_scope("phases"):
+                    up, vp, um_l, vm_l = post_k(
+                        offs, dt11, pad_ext(u), pad_ext(v), pad_ext(f),
+                        pad_ext(g), pad_ext(p), *post_extra,
+                    )
+                    u = unpad_ext(up)
+                    v = unpad_ext(vp)
+                    # next step's CFL maxima: POST's carried per-shard maxima
+                    # over the valid extended cells — the same global value
+                    # set the serial step's exchanged-block scan sees
+                    um = reduction(um_l, comm, "max")
+                    vm = reduction(vm_l, comm, "max")
+                    # post step N+1's exchange NOW: its results feed only the
+                    # carried buffers (the boundary half, one iteration
+                    # later) — nothing else in the trace depends on them
+                    ud, vd = exchange_buffers(u, v)
+                    t_next = t + dt.astype(idx_dtype)
+                    if _flags.verbose():
+                        master_print(comm, "TIME {} , TIMESTEP {}", t_next, dt)
+                    capt = (next_cap(_res, _it),) if cap is not None else ()
+                    return (u, v, p, t_next, nt + 1, ud, vd, um, vm, nt + 1,
+                            _res, _it, dt) + capt
 
         step_impl = step if fused_k is None else step_fused
         te = param.te

@@ -30,6 +30,7 @@ from jax import lax
 from ..ops import ns3d as ops
 from ..utils import faultinject as _fi
 from ..utils import flags as _flags
+from ..utils import profiling as _prof
 from ..utils import telemetry as _tm
 from ._driver import clamped_dt
 from ..utils.grid import Grid
@@ -347,7 +348,7 @@ class NS3DSolver:
                 stall_rtol=param.tpu_mg_stall_rtol,
                 mg_fused=param.tpu_mg_fused,
             )
-        return solve
+        return _prof.scoped("solve", solve)
 
     def _build_step(self, backend: str = "auto", instrumented: bool = False):
         """One traced timestep. instrumented=True returns the SAME pipeline
@@ -374,47 +375,52 @@ class NS3DSolver:
         faults = getattr(self, "_field_faults", ())
 
         def step(u, v, w, p, t, nt):
-            u, v, w, p = _fi.apply_field_faults(faults, nt, u=u, v=v, w=w,
-                                                p=p)
-            if adaptive:
-                dt = ops.compute_timestep_3d(
-                    u, v, w, jnp.asarray(self.dt_bound, dtype), dx, dy, dz, param.tau
-                )
-            else:
-                dt = jnp.asarray(param.dt, dtype)
-            dt = clamped_dt(dt, dt_scale)
-            u, v, w = ops.set_boundary_conditions_3d(u, v, w, bcs)
-            if problem == "dcavity":
-                u = ops.set_special_bc_dcavity_3d(u)
-            elif problem == "canal":
-                u = ops.set_special_bc_canal_3d(u)
-            if masks is not None:
-                from ..ops.obstacle3d import (
-                    adapt_uvw_obstacle,
-                    apply_obstacle_velocity_bc_3d,
-                    mask_fgh,
-                )
+            with jax.named_scope("phases"):
+                u, v, w, p = _fi.apply_field_faults(faults, nt, u=u, v=v,
+                                                    w=w, p=p)
+                if adaptive:
+                    dt = ops.compute_timestep_3d(
+                        u, v, w, jnp.asarray(self.dt_bound, dtype),
+                        dx, dy, dz, param.tau
+                    )
+                else:
+                    dt = jnp.asarray(param.dt, dtype)
+                dt = clamped_dt(dt, dt_scale)
+                u, v, w = ops.set_boundary_conditions_3d(u, v, w, bcs)
+                if problem == "dcavity":
+                    u = ops.set_special_bc_dcavity_3d(u)
+                elif problem == "canal":
+                    u = ops.set_special_bc_canal_3d(u)
+                if masks is not None:
+                    from ..ops.obstacle3d import (
+                        adapt_uvw_obstacle,
+                        apply_obstacle_velocity_bc_3d,
+                        mask_fgh,
+                    )
 
-                u, v, w = apply_obstacle_velocity_bc_3d(u, v, w, masks)
-            f, g_, h = ops.compute_fgh(
-                u, v, w, dt, param.re, param.gx, param.gy, param.gz,
-                param.gamma, dx, dy, dz,
-            )
-            if masks is not None:
-                f, g_, h = mask_fgh(f, g_, h, u, v, w, masks)
-            rhs = ops.compute_rhs(f, g_, h, dt, dx, dy, dz)
-            p, _res, _it = solve(p, rhs)
-            if masks is not None:
-                u, v, w = adapt_uvw_obstacle(
-                    u, v, w, f, g_, h, p, dt, dx, dy, dz, masks
+                    u, v, w = apply_obstacle_velocity_bc_3d(u, v, w, masks)
+                f, g_, h = ops.compute_fgh(
+                    u, v, w, dt, param.re, param.gx, param.gy, param.gz,
+                    param.gamma, dx, dy, dz,
                 )
-            else:
-                u, v, w = ops.adapt_uvw(u, v, w, f, g_, h, p, dt, dx, dy, dz)
-            time_dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-            t_next = t + dt.astype(time_dtype)
-            if _flags.verbose():
-                # printed AFTER t += dt, matching A6 main.c:58-62
-                jax.debug.print("TIME {} , TIMESTEP {}", t_next, dt)
+                if masks is not None:
+                    f, g_, h = mask_fgh(f, g_, h, u, v, w, masks)
+                rhs = ops.compute_rhs(f, g_, h, dt, dx, dy, dz)
+            p, _res, _it = solve(p, rhs)
+            with jax.named_scope("phases"):
+                if masks is not None:
+                    u, v, w = adapt_uvw_obstacle(
+                        u, v, w, f, g_, h, p, dt, dx, dy, dz, masks
+                    )
+                else:
+                    u, v, w = ops.adapt_uvw(u, v, w, f, g_, h, p, dt,
+                                            dx, dy, dz)
+                time_dtype = (jnp.float64 if jax.config.jax_enable_x64
+                              else jnp.float32)
+                t_next = t + dt.astype(time_dtype)
+                if _flags.verbose():
+                    # printed AFTER t += dt, matching A6 main.c:58-62
+                    jax.debug.print("TIME {} , TIMESTEP {}", t_next, dt)
             if instrumented:
                 return u, v, w, p, t_next, nt + 1, _res, _it, dt
             return u, v, w, p, t_next, nt + 1
@@ -462,24 +468,26 @@ class NS3DSolver:
         time_dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
 
         def step(up, vp, wp, p, t, nt, umax, vmax, wmax):
-            up, vp, wp, p = _fi.apply_field_faults(faults, nt, u=up, v=vp,
-                                                   w=wp, p=p)
-            if adaptive:
-                dt = ops.cfl_dt_3d(umax, vmax, wmax, dt_bound, dx, dy, dz,
-                                   param.tau)
-            else:
-                dt = jnp.asarray(param.dt, dtype)
-            dt = clamped_dt(dt, dt_scale)
-            dt11 = jnp.full((1, 1), dt, dtype)
-            up, vp, wp, fp, gp, hp, rhsp = pre(offs, dt11, up, vp, wp)
-            rhs = unpad3(rhsp)
+            with jax.named_scope("phases"):
+                up, vp, wp, p = _fi.apply_field_faults(
+                    faults, nt, u=up, v=vp, w=wp, p=p)
+                if adaptive:
+                    dt = ops.cfl_dt_3d(umax, vmax, wmax, dt_bound,
+                                       dx, dy, dz, param.tau)
+                else:
+                    dt = jnp.asarray(param.dt, dtype)
+                dt = clamped_dt(dt, dt_scale)
+                dt11 = jnp.full((1, 1), dt, dtype)
+                up, vp, wp, fp, gp, hp, rhsp = pre(offs, dt11, up, vp, wp)
+                rhs = unpad3(rhsp)
             p, _res, _it = solve(p, rhs)
-            up, vp, wp, umax, vmax, wmax = post(
-                offs, dt11, up, vp, wp, fp, gp, hp, pad3(p)
-            )
-            t_next = t + dt.astype(time_dtype)
-            if _flags.verbose():
-                jax.debug.print("TIME {} , TIMESTEP {}", t_next, dt)
+            with jax.named_scope("phases"):
+                up, vp, wp, umax, vmax, wmax = post(
+                    offs, dt11, up, vp, wp, fp, gp, hp, pad3(p)
+                )
+                t_next = t + dt.astype(time_dtype)
+                if _flags.verbose():
+                    jax.debug.print("TIME {} , TIMESTEP {}", t_next, dt)
             if metrics:
                 return (up, vp, wp, p, t_next, nt + 1, umax, vmax, wmax,
                         _res, _it, dt)

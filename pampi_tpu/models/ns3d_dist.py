@@ -61,6 +61,7 @@ from ..parallel.stencil3d import (
 from ..utils import dispatch as _dispatch
 from ..utils import faultinject as _fi
 from ..utils import flags as _flags
+from ..utils import profiling as _prof
 from ..utils import telemetry as _tm
 from ..utils import xprof as _xprof
 from ._driver import clamped_dt
@@ -546,6 +547,9 @@ class NS3DDistSolver:
         adapt_n = int(param.tpu_itermax_adaptive)
         use_cap = adapt_n > 0 and solve in (
             _solve_sor, _solve_sor_split, _solve_sor_octants)
+        # the solve (its layout pack/unpack and exchanges included) under
+        # one named scope, the rest of the step under "phases"
+        solve = _prof.scoped("solve", solve)
         if adapt_n > 0:
             _dispatch.record(
                 "itermax_adaptive_ns3d_dist",
@@ -632,82 +636,84 @@ class NS3DDistSolver:
         idx_dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
 
         def step(u, v, w, p, t, nt, cap=None):
-            u, v, w, p = _fi.apply_field_faults(field_faults, nt, u=u, v=v,
-                                                w=w, p=p)
-            u = halo_exchange(u, comm)
-            v = halo_exchange(v, comm)
-            w = halo_exchange(w, comm)
-            dt = compute_dt(u, v, w) if adaptive else jnp.asarray(param.dt, dtype)
-            dt = clamped_dt(dt, dt_scale)
-            u, v, w = set_bcs(u, v, w)
-            u = set_special_bc(u)
-            u = halo_exchange(u, comm)
-            v = halo_exchange(v, comm)
-            w = halo_exchange(w, comm)
-            if gmasks is not None:
-                # needs the fully-exchanged post-BC state (the single-device
-                # op reads the whole array at once); its own halo-cell
-                # outputs are refreshed by one more exchange
-                u, v, w = apply_obstacle_velocity_bc_3d(u, v, w, local_masks())
+            with jax.named_scope("phases"):
+                u, v, w, p = _fi.apply_field_faults(field_faults, nt, u=u, v=v,
+                                                    w=w, p=p)
                 u = halo_exchange(u, comm)
                 v = halo_exchange(v, comm)
                 w = halo_exchange(w, comm)
-            f, g_, h = ops.compute_fgh_interior(
-                u, v, w, dt, param.re, param.gx, param.gy, param.gz,
-                param.gamma, dx, dy, dz,
-            )
-            f, g_, h = fgh_fixups(f, g_, h, u, v, w)
-            if gmasks is not None:
-                f, g_, h = mask_fgh(f, g_, h, u, v, w, local_masks())
-            f = halo_shift(f, comm, "i")
-            g_ = halo_shift(g_, comm, "j")
-            h = halo_shift(h, comm, "k")
-            rhs = ops.compute_rhs(f, g_, h, dt, dx, dy, dz)
+                dt = compute_dt(u, v, w) if adaptive else jnp.asarray(param.dt, dtype)
+                dt = clamped_dt(dt, dt_scale)
+                u, v, w = set_bcs(u, v, w)
+                u = set_special_bc(u)
+                u = halo_exchange(u, comm)
+                v = halo_exchange(v, comm)
+                w = halo_exchange(w, comm)
+                if gmasks is not None:
+                    # needs the fully-exchanged post-BC state (the single-device
+                    # op reads the whole array at once); its own halo-cell
+                    # outputs are refreshed by one more exchange
+                    u, v, w = apply_obstacle_velocity_bc_3d(u, v, w, local_masks())
+                    u = halo_exchange(u, comm)
+                    v = halo_exchange(v, comm)
+                    w = halo_exchange(w, comm)
+                f, g_, h = ops.compute_fgh_interior(
+                    u, v, w, dt, param.re, param.gx, param.gy, param.gz,
+                    param.gamma, dx, dy, dz,
+                )
+                f, g_, h = fgh_fixups(f, g_, h, u, v, w)
+                if gmasks is not None:
+                    f, g_, h = mask_fgh(f, g_, h, u, v, w, local_masks())
+                f = halo_shift(f, comm, "i")
+                g_ = halo_shift(g_, comm, "j")
+                h = halo_shift(h, comm, "k")
+                rhs = ops.compute_rhs(f, g_, h, dt, dx, dy, dz)
             p, res, it = (solve(p, rhs, cap) if cap is not None
                           else solve(p, rhs))
 
-            def adapt(u, v, w):
-                if gmasks is not None:
-                    return adapt_uvw_obstacle(
-                        u, v, w, f, g_, h, p, dt, dx, dy, dz, local_masks()
+            with jax.named_scope("phases"):
+                def adapt(u, v, w):
+                    if gmasks is not None:
+                        return adapt_uvw_obstacle(
+                            u, v, w, f, g_, h, p, dt, dx, dy, dz, local_masks()
+                        )
+                    return ops.adapt_uvw(u, v, w, f, g_, h, p, dt, dx, dy, dz)
+
+                if not self.ragged:
+                    u, v, w = adapt(u, v, w)
+                else:
+                    # ragged projection: only the true global interior updates;
+                    # interior-stored ghost planes keep their BC-era values and
+                    # dead cells are zeroed (see models/ns2d_dist.py). One
+                    # gating block for the plain AND obstacle projections.
+                    from ..parallel import ragged3d as rg3
+
+                    gk, gj, gi = rg3.global_index_grids(comm, kl, jl, il)
+                    interior = (
+                        (gk >= 1) & (gk <= g.kmax)
+                        & (gj >= 1) & (gj <= g.jmax)
+                        & (gi >= 1) & (gi <= g.imax)
                     )
-                return ops.adapt_uvw(u, v, w, f, g_, h, p, dt, dx, dy, dz)
-
-            if not self.ragged:
-                u, v, w = adapt(u, v, w)
-            else:
-                # ragged projection: only the true global interior updates;
-                # interior-stored ghost planes keep their BC-era values and
-                # dead cells are zeroed (see models/ns2d_dist.py). One
-                # gating block for the plain AND obstacle projections.
-                from ..parallel import ragged3d as rg3
-
-                gk, gj, gi = rg3.global_index_grids(comm, kl, jl, il)
-                interior = (
-                    (gk >= 1) & (gk <= g.kmax)
-                    & (gj >= 1) & (gj <= g.jmax)
-                    & (gi >= 1) & (gi <= g.imax)
-                )
-                live = rg3.live_masks_3d(
-                    comm, kl, jl, il, g.kmax, g.jmax, g.imax, dtype
-                )
-                ua, va, wa = adapt(u, v, w)
-                u = jnp.where(interior, ua, u) * live
-                v = jnp.where(interior, va, v) * live
-                w = jnp.where(interior, wa, w) * live
-            t_next = t + dt.astype(idx_dtype)
-            if _flags.verbose():
-                # printed AFTER t += dt, matching A6 main.c:58-62
-                master_print(comm, "TIME {} , TIMESTEP {}", t_next, dt)
-            capt = (next_cap(res, it),) if cap is not None else ()
-            if metrics:
-                # mesh-global maxima (replicated) — telemetry scalars
-                um = reduction(jnp.max(jnp.abs(u)), comm, "max")
-                vm = reduction(jnp.max(jnp.abs(v)), comm, "max")
-                wm = reduction(jnp.max(jnp.abs(w)), comm, "max")
-                return (u, v, w, p, t_next, nt + 1, res, it, dt,
-                        um, vm, wm) + capt
-            return (u, v, w, p, t_next, nt + 1) + capt
+                    live = rg3.live_masks_3d(
+                        comm, kl, jl, il, g.kmax, g.jmax, g.imax, dtype
+                    )
+                    ua, va, wa = adapt(u, v, w)
+                    u = jnp.where(interior, ua, u) * live
+                    v = jnp.where(interior, va, v) * live
+                    w = jnp.where(interior, wa, w) * live
+                t_next = t + dt.astype(idx_dtype)
+                if _flags.verbose():
+                    # printed AFTER t += dt, matching A6 main.c:58-62
+                    master_print(comm, "TIME {} , TIMESTEP {}", t_next, dt)
+                capt = (next_cap(res, it),) if cap is not None else ()
+                if metrics:
+                    # mesh-global maxima (replicated) — telemetry scalars
+                    um = reduction(jnp.max(jnp.abs(u)), comm, "max")
+                    vm = reduction(jnp.max(jnp.abs(v)), comm, "max")
+                    wm = reduction(jnp.max(jnp.abs(w)), comm, "max")
+                    return (u, v, w, p, t_next, nt + 1, res, it, dt,
+                            um, vm, wm) + capt
+                return (u, v, w, p, t_next, nt + 1) + capt
 
         def step_fused(u, v, w, p, t, nt, cap=None, strips=None):
             """The fused-phase twin of step() (see models/ns2d_dist.py):
@@ -718,73 +724,75 @@ class NS3DDistSolver:
             the K-block's captured strips instead of exchanging."""
             from ..parallel.comm import get_offsets
 
-            pre_k, post_k = fused_k
-            H = FUSE_DEEP_HALO
-            u, v, w, p = _fi.apply_field_faults(field_faults, nt, u=u, v=v,
-                                                w=w, p=p)
-            if strips is None:
-                ud = halo_exchange(embed_deep(u, H), comm, depth=H)
-                vd = halo_exchange(embed_deep(v, H), comm, depth=H)
-                wd = halo_exchange(embed_deep(w, H), comm, depth=H)
-            else:
-                from ..parallel.comm import paste_axis_strips
+            with jax.named_scope("phases"):
+                pre_k, post_k = fused_k
+                H = FUSE_DEEP_HALO
+                u, v, w, p = _fi.apply_field_faults(field_faults, nt, u=u, v=v,
+                                                    w=w, p=p)
+                if strips is None:
+                    ud = halo_exchange(embed_deep(u, H), comm, depth=H)
+                    vd = halo_exchange(embed_deep(v, H), comm, depth=H)
+                    wd = halo_exchange(embed_deep(w, H), comm, depth=H)
+                else:
+                    from ..parallel.comm import paste_axis_strips
 
-                (lo_u, hi_u), (lo_v, hi_v), (lo_w, hi_w) = strips
-                ud = paste_axis_strips(
-                    embed_deep(u, H), comm, dax, H, lo_u, hi_u)
-                vd = paste_axis_strips(
-                    embed_deep(v, H), comm, dax, H, lo_v, hi_v)
-                wd = paste_axis_strips(
-                    embed_deep(w, H), comm, dax, H, lo_w, hi_w)
-            # ghost-inclusive CFL max over the deep blocks: same global
-            # value set as the exchanged extended blocks
-            dt = (compute_dt(ud, vd, wd) if adaptive
-                  else jnp.asarray(param.dt, dtype))
-            dt = clamped_dt(dt, dt_scale)
-            offs = jnp.stack([
-                get_offsets("k", kl), get_offsets("j", jl),
-                get_offsets("i", il),
-            ]).astype(jnp.int32)
-            dt11 = jnp.full((1, 1), dt, dtype)
-            pre_extra = post_extra = ()
-            if gmasks is not None:
-                flg_deep, flg_ext = fused_flag_blocks()
-                pre_extra = (flg_deep,)
-                post_extra = (flg_ext,)
-            upd, vpd, wpd, fpd, gpd, hpd, rpd = pre_k(
-                offs, dt11, pad_deep(ud), pad_deep(vd), pad_deep(wd),
-                *pre_extra,
-            )
-            u = strip_deep(unpad_deep(upd), H)
-            v = strip_deep(unpad_deep(vpd), H)
-            w = strip_deep(unpad_deep(wpd), H)
-            f = strip_deep(unpad_deep(fpd), H)
-            g_ = strip_deep(unpad_deep(gpd), H)
-            h = strip_deep(unpad_deep(hpd), H)
-            rhs = strip_deep(unpad_deep(rpd), H)
+                    (lo_u, hi_u), (lo_v, hi_v), (lo_w, hi_w) = strips
+                    ud = paste_axis_strips(
+                        embed_deep(u, H), comm, dax, H, lo_u, hi_u)
+                    vd = paste_axis_strips(
+                        embed_deep(v, H), comm, dax, H, lo_v, hi_v)
+                    wd = paste_axis_strips(
+                        embed_deep(w, H), comm, dax, H, lo_w, hi_w)
+                # ghost-inclusive CFL max over the deep blocks: same global
+                # value set as the exchanged extended blocks
+                dt = (compute_dt(ud, vd, wd) if adaptive
+                      else jnp.asarray(param.dt, dtype))
+                dt = clamped_dt(dt, dt_scale)
+                offs = jnp.stack([
+                    get_offsets("k", kl), get_offsets("j", jl),
+                    get_offsets("i", il),
+                ]).astype(jnp.int32)
+                dt11 = jnp.full((1, 1), dt, dtype)
+                pre_extra = post_extra = ()
+                if gmasks is not None:
+                    flg_deep, flg_ext = fused_flag_blocks()
+                    pre_extra = (flg_deep,)
+                    post_extra = (flg_ext,)
+                upd, vpd, wpd, fpd, gpd, hpd, rpd = pre_k(
+                    offs, dt11, pad_deep(ud), pad_deep(vd), pad_deep(wd),
+                    *pre_extra,
+                )
+                u = strip_deep(unpad_deep(upd), H)
+                v = strip_deep(unpad_deep(vpd), H)
+                w = strip_deep(unpad_deep(wpd), H)
+                f = strip_deep(unpad_deep(fpd), H)
+                g_ = strip_deep(unpad_deep(gpd), H)
+                h = strip_deep(unpad_deep(hpd), H)
+                rhs = strip_deep(unpad_deep(rpd), H)
             p, _res, _it = (solve(p, rhs, cap) if cap is not None
                             else solve(p, rhs))
-            up, vp, wp, um_l, vm_l, wm_l = post_k(
-                offs, dt11, pad_ext(u), pad_ext(v), pad_ext(w),
-                pad_ext(f), pad_ext(g_), pad_ext(h), pad_ext(p),
-                *post_extra,
-            )
-            u = unpad_ext(up)
-            v = unpad_ext(vp)
-            w = unpad_ext(wp)
-            t_next = t + dt.astype(idx_dtype)
-            if _flags.verbose():
-                master_print(comm, "TIME {} , TIMESTEP {}", t_next, dt)
-            capt = (next_cap(_res, _it),) if cap is not None else ()
-            if metrics:
-                # the POST kernel's maxima are per-shard: Allreduce MAX
-                # makes them the global telemetry scalars
-                um = reduction(um_l, comm, "max")
-                vm = reduction(vm_l, comm, "max")
-                wm = reduction(wm_l, comm, "max")
-                return (u, v, w, p, t_next, nt + 1, _res, _it, dt,
-                        um, vm, wm) + capt
-            return (u, v, w, p, t_next, nt + 1) + capt
+            with jax.named_scope("phases"):
+                up, vp, wp, um_l, vm_l, wm_l = post_k(
+                    offs, dt11, pad_ext(u), pad_ext(v), pad_ext(w),
+                    pad_ext(f), pad_ext(g_), pad_ext(h), pad_ext(p),
+                    *post_extra,
+                )
+                u = unpad_ext(up)
+                v = unpad_ext(vp)
+                w = unpad_ext(wp)
+                t_next = t + dt.astype(idx_dtype)
+                if _flags.verbose():
+                    master_print(comm, "TIME {} , TIMESTEP {}", t_next, dt)
+                capt = (next_cap(_res, _it),) if cap is not None else ()
+                if metrics:
+                    # the POST kernel's maxima are per-shard: Allreduce MAX
+                    # makes them the global telemetry scalars
+                    um = reduction(um_l, comm, "max")
+                    vm = reduction(vm_l, comm, "max")
+                    wm = reduction(wm_l, comm, "max")
+                    return (u, v, w, p, t_next, nt + 1, _res, _it, dt,
+                            um, vm, wm) + capt
+                return (u, v, w, p, t_next, nt + 1) + capt
 
         if overlap:
             # -- overlapped fused step (parallel/overlap.py; see
@@ -837,53 +845,55 @@ class NS3DDistSolver:
 
             def step_overlap(u, v, w, p, t, nt, ud, vd, wd,
                              um, vm, wm, gen, cap=None):
-                pre_k, post_k = fused_k
-                pre_i = pre_int if pre_int is not None else pre_k
-                pre_b = pre_bnd if pre_bnd is not None else pre_k
-                dt = (cfl_from_maxima(um, vm, wm) if adaptive
-                      else jnp.asarray(param.dt, dtype))
-                dt = _ovl.generation_guard(dt, gen, nt)
-                dt = clamped_dt(dt, dt_scale)
-                offs = jnp.stack([
-                    get_offsets("k", kl), get_offsets("j", jl),
-                    get_offsets("i", il),
-                ]).astype(jnp.int32)
-                dt11 = jnp.full((1, 1), dt, dtype)
-                pre_extra = post_extra = ()
-                if gmasks is not None:
-                    flg_deep, flg_ext = fused_flag_blocks()
-                    pre_extra = (flg_deep,)
-                    post_extra = (flg_ext,)
-                ints = pre_i(offs, dt11, pad_deep(embed_deep(u, H3)),
-                             pad_deep(embed_deep(v, H3)),
-                             pad_deep(embed_deep(w, H3)), *pre_extra)
-                bnds = pre_b(offs, dt11, pad_deep(ud), pad_deep(vd),
-                             pad_deep(wd), *pre_extra)
-                u, v, w, f, g_, h, rhs = _ovl.merge_halves(
-                    int_mask,
-                    [strip_deep(unpad_deep(a), H3) for a in ints],
-                    [strip_deep(unpad_deep(b), H3) for b in bnds])
+                with jax.named_scope("phases"):
+                    pre_k, post_k = fused_k
+                    pre_i = pre_int if pre_int is not None else pre_k
+                    pre_b = pre_bnd if pre_bnd is not None else pre_k
+                    dt = (cfl_from_maxima(um, vm, wm) if adaptive
+                          else jnp.asarray(param.dt, dtype))
+                    dt = _ovl.generation_guard(dt, gen, nt)
+                    dt = clamped_dt(dt, dt_scale)
+                    offs = jnp.stack([
+                        get_offsets("k", kl), get_offsets("j", jl),
+                        get_offsets("i", il),
+                    ]).astype(jnp.int32)
+                    dt11 = jnp.full((1, 1), dt, dtype)
+                    pre_extra = post_extra = ()
+                    if gmasks is not None:
+                        flg_deep, flg_ext = fused_flag_blocks()
+                        pre_extra = (flg_deep,)
+                        post_extra = (flg_ext,)
+                    ints = pre_i(offs, dt11, pad_deep(embed_deep(u, H3)),
+                                 pad_deep(embed_deep(v, H3)),
+                                 pad_deep(embed_deep(w, H3)), *pre_extra)
+                    bnds = pre_b(offs, dt11, pad_deep(ud), pad_deep(vd),
+                                 pad_deep(wd), *pre_extra)
+                    u, v, w, f, g_, h, rhs = _ovl.merge_halves(
+                        int_mask,
+                        [strip_deep(unpad_deep(a), H3) for a in ints],
+                        [strip_deep(unpad_deep(b), H3) for b in bnds])
                 p, _res, _it = (solve(p, rhs, cap) if cap is not None
                                 else solve(p, rhs))
-                up, vp, wp, um_l, vm_l, wm_l = post_k(
-                    offs, dt11, pad_ext(u), pad_ext(v), pad_ext(w),
-                    pad_ext(f), pad_ext(g_), pad_ext(h), pad_ext(p),
-                    *post_extra,
-                )
-                u = unpad_ext(up)
-                v = unpad_ext(vp)
-                w = unpad_ext(wp)
-                um = reduction(um_l, comm, "max")
-                vm = reduction(vm_l, comm, "max")
-                wm = reduction(wm_l, comm, "max")
-                # post the next step's exchange into the double buffer
-                ud, vd, wd = exchange_buffers(u, v, w)
-                t_next = t + dt.astype(idx_dtype)
-                if _flags.verbose():
-                    master_print(comm, "TIME {} , TIMESTEP {}", t_next, dt)
-                capt = (next_cap(_res, _it),) if cap is not None else ()
-                return (u, v, w, p, t_next, nt + 1, ud, vd, wd,
-                        um, vm, wm, nt + 1, _res, _it, dt) + capt
+                with jax.named_scope("phases"):
+                    up, vp, wp, um_l, vm_l, wm_l = post_k(
+                        offs, dt11, pad_ext(u), pad_ext(v), pad_ext(w),
+                        pad_ext(f), pad_ext(g_), pad_ext(h), pad_ext(p),
+                        *post_extra,
+                    )
+                    u = unpad_ext(up)
+                    v = unpad_ext(vp)
+                    w = unpad_ext(wp)
+                    um = reduction(um_l, comm, "max")
+                    vm = reduction(vm_l, comm, "max")
+                    wm = reduction(wm_l, comm, "max")
+                    # post the next step's exchange into the double buffer
+                    ud, vd, wd = exchange_buffers(u, v, w)
+                    t_next = t + dt.astype(idx_dtype)
+                    if _flags.verbose():
+                        master_print(comm, "TIME {} , TIMESTEP {}", t_next, dt)
+                    capt = (next_cap(_res, _it),) if cap is not None else ()
+                    return (u, v, w, p, t_next, nt + 1, ud, vd, wd,
+                            um, vm, wm, nt + 1, _res, _it, dt) + capt
 
         step_impl = step if fused_k is None else step_fused
         te = param.te

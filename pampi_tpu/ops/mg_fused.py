@@ -406,6 +406,7 @@ def make_cycle_kernels(levels, spacings, dtype, n_pre: int = 2,
     down_call = pl.pallas_call(
         functools.partial(_down_body, L=L, nd=nd, n_pre=n_pre,
                           parities=parities, masked=masked),
+        name="mg2d_down",
         grid=(1,),
         in_specs=[smem, smem]
         + ([_vspec(stack_shape)] * 2 if masked else [])
@@ -418,6 +419,7 @@ def make_cycle_kernels(levels, spacings, dtype, n_pre: int = 2,
     up_call = pl.pallas_call(
         functools.partial(_up_body, L=L, nd=nd, n_post=n_post,
                           parities=parities, masked=masked),
+        name="mg2d_up",
         grid=(1,),
         in_specs=[smem, smem]
         + ([_vspec(stack_shape)] * 2 if masked else [])
@@ -558,6 +560,7 @@ def make_class_cycle_2d(jmax_c: int, imax_c: int, dtype, n_pre: int = 2,
     call = pl.pallas_call(
         functools.partial(_class_cycle_body, lmax=lmax, n_pre=n_pre,
                           n_post=n_post, n_bottom=n_bottom),
+        name="mg2d_class_cycle",
         grid=(1,),
         in_specs=[smem, smem,
                   pl.BlockSpec(plane, lambda i: (0, 0)),

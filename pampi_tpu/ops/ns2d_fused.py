@@ -779,6 +779,7 @@ def make_fused_pre_2d(
     ]
     pre_call = pl.pallas_call(
         pre_kernel,
+        name="ns2d_pre",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nblocks,),
@@ -881,6 +882,7 @@ def make_fused_post_2d(
     ]
     post_call = pl.pallas_call(
         post_kernel,
+        name="ns2d_post",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nblocks,),

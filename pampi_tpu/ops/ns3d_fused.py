@@ -786,6 +786,7 @@ def make_fused_pre_3d(
     ]
     call = pl.pallas_call(
         kernel,
+        name="ns3d_pre",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nblocks,),
@@ -888,6 +889,7 @@ def make_fused_post_3d(
     ]
     call = pl.pallas_call(
         kernel,
+        name="ns3d_post",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nblocks,),

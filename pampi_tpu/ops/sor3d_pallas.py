@@ -387,6 +387,7 @@ def make_rb_iter_tblock_3d(
     ]
     call = pl.pallas_call(
         kernel,
+        name="sor3d_rb_tblock",
         grid=(nblocks,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n_in,
         out_specs=[
@@ -734,6 +735,7 @@ def make_rb_iter_tblock_3d_octants(
     )
     call = pl.pallas_call(
         kernel,
+        name="sor3d_rb_octants",
         grid=(nblocks,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=[

@@ -295,6 +295,7 @@ def make_rb_iters_obsdist(jmax, imax, jl, il, n, dx, dy, omega, dtype, *,
     )
     call = pl.pallas_call(
         kernel,
+        name="sor2d_rb_obsdist",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((rp, wp), dtype),

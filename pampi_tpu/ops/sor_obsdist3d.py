@@ -226,6 +226,7 @@ def make_rb_iters_obsdist_3d(kmax, jmax, imax, kl, jl, il, n, dx, dy, dz,
     )
     call = pl.pallas_call(
         kernel,
+        name="sor3d_rb_obsdist",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((kp, jp, ip), dtype),

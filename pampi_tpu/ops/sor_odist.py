@@ -263,6 +263,7 @@ def make_rb_iters_odist(g: OGeom, dx: float, dy: float, dz: float,
     )
     call = pl.pallas_call(
         kernel,
+        name="sor3d_rb_odist",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((8, g.sp, g.jp2, g.ip2), dtype),

@@ -607,6 +607,7 @@ def make_rb_iter_tblock(
         in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2
     call = pl.pallas_call(
         kernel,
+        name="sor2d_rb_tblock",
         grid=(nblocks,),
         in_specs=in_specs,
         out_specs=[
@@ -951,6 +952,7 @@ def make_rb_iter_tblock_quarters(
     )
     call = pl.pallas_call(
         kernel,
+        name="sor2d_rb_quarters",
         grid=(nblocks,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=[
@@ -1036,6 +1038,7 @@ def make_rb_iter_pallas(
 
     call = pl.pallas_call(
         kernel,
+        name="sor2d_rb",
         grid=(2, nblocks),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),

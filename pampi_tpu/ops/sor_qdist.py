@@ -248,6 +248,7 @@ def make_rb_iters_qdist(g: QGeom, dx: float, dy: float, omega: float, dtype,
     )
     call = pl.pallas_call(
         kernel,
+        name="sor2d_rb_qdist",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((4, g.rp, g.w2p), dtype),

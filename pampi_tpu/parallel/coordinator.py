@@ -94,6 +94,7 @@ import warnings
 import numpy as np
 
 from ..utils import faultinject as _fi
+from ..utils import profiling as _prof
 from ..utils import telemetry as _tm
 
 # the fault word: one int64 per slot, merged elementwise with _MERGE_OPS.
@@ -394,9 +395,11 @@ class CoordinatedLoop:
         try:
             with _fi.rank_scope(self.rank):
                 _fi.maybe_chunk_fault()  # injected fault plane (test-only)
-                pending = self.chunk_fn(*self._confirmed)
+                with _prof.region("drive.dispatch"):
+                    pending = self.chunk_fn(*self._confirmed)
                 # force completion: async runtime faults surface here
-                t = float(pending[self.time_index])
+                with _prof.region("drive.wait"):
+                    t = float(pending[self.time_index])
         except Exception as exc:  # lint: allow(broad-except) — the fault-classification funnel, same contract as drive_chunks
             if isinstance(exc, _fi.FaultSpecError):
                 raise  # a broken TEST spec fails loudly, never classified
@@ -556,27 +559,28 @@ class CoordinatedLoop:
                     and self._clean >= self.replenish_after
                     and self._budget < self._max_budget):
                 self._budget = self._max_budget
-            restore = getattr(self.retry, "on_clean_chunk", None)
-            if restore is not None:
-                # deterministic on every rank: the clean streak advances
-                # at agreed boundaries only, so all ranks restore their
-                # pallas chunk at the SAME boundary
-                restored_fn = restore()
-                if restored_fn is not None:
-                    self.chunk_fn = restored_fn
-            if self.bar is not None:
-                self.bar.update(self._t_pending)
-            if self.on_state is not None:
-                self.on_state(self._confirmed)
-            if merged[W_CKPT] and self.on_ckpt is not None:
-                self._emit("ckpt", t=self._t_pending)
-                if getattr(self.on_ckpt, "takes_ledger", False):
-                    # the coordinated writer persists the fault ledger
-                    # into the elastic manifest alongside the fields
-                    # (models/_driver.coord_ckpt_cadence marks itself)
-                    self.on_ckpt(self._confirmed, ledger=self.ledger())
-                else:
-                    self.on_ckpt(self._confirmed)
+            with _prof.region("drive.sync"):
+                restore = getattr(self.retry, "on_clean_chunk", None)
+                if restore is not None:
+                    # deterministic on every rank: the clean streak advances
+                    # at agreed boundaries only, so all ranks restore their
+                    # pallas chunk at the SAME boundary
+                    restored_fn = restore()
+                    if restored_fn is not None:
+                        self.chunk_fn = restored_fn
+                if self.bar is not None:
+                    self.bar.update(self._t_pending)
+                if self.on_state is not None:
+                    self.on_state(self._confirmed)
+                if merged[W_CKPT] and self.on_ckpt is not None:
+                    self._emit("ckpt", t=self._t_pending)
+                    if getattr(self.on_ckpt, "takes_ledger", False):
+                        # the coordinated writer persists the fault ledger
+                        # into the elastic manifest alongside the fields
+                        # (models/_driver.coord_ckpt_cadence marks itself)
+                        self.on_ckpt(self._confirmed, ledger=self.ledger())
+                    else:
+                        self.on_ckpt(self._confirmed)
             if self._t_pending > self.te:
                 self._local_done = True
         if merged[W_DONE]:

@@ -4,11 +4,19 @@ Capability parity with the reference's LIKWID marker layer
 (/root/reference/assignment-4/src/likwid-marker.h:104-130: START/STOP region
 macros that compile to no-ops unless -DLIKWID_PERFMON) re-designed for the
 TPU stack: regions become `jax.profiler` trace annotations (visible in a
-TensorBoard/XProf trace) plus optional wall-clock accounting, and the no-op
-switch is the PAMPI_PROFILE environment variable instead of a compile flag.
+TensorBoard/XProf trace) plus optional wall-clock accounting, and the
+accounting switch is the PAMPI_PROFILE environment variable instead of a
+compile flag.
 
-  PAMPI_PROFILE=0/unset  every call is a no-op (the likwid default)
-  PAMPI_PROFILE=1        region wall-clock accounting + trace annotations
+`region` is the program's one host span: it ALWAYS enters the trace
+annotation, so every region shows in any active profiler trace on the
+device's clock (a few microseconds when no session is active). `scoped`
+is its device-side twin: a traced callable whose ops carry the scope in
+their op_name metadata (jax.named_scope).
+
+  PAMPI_PROFILE=0/unset  no accounting: the region table, CSV and the
+                         drive loop's ETA line are off
+  PAMPI_PROFILE=1        region wall-clock accounting
   PAMPI_PROFILE=<dir>    additionally jax.profiler.start_trace(<dir>) on
                          init and stop on finalize (full XProf trace)
   PAMPI_PROFILE_CSV=<f>  finalize() additionally writes the region table as
@@ -28,6 +36,7 @@ Usage (mirrors LIKWID_MARKER_*):
 from __future__ import annotations
 
 import contextlib
+import functools
 import sys
 import time
 from collections import defaultdict
@@ -79,18 +88,31 @@ def init() -> None:
 
 @contextlib.contextmanager
 def region(name: str):
-    """≙ LIKWID_MARKER_START/STOP pair. Also a jax.profiler annotation so the
-    region shows up on the device timeline."""
-    if not enabled():
-        yield
-        return
+    """≙ LIKWID_MARKER_START/STOP pair: a jax.profiler annotation, always, so
+    the region shows up on the device timeline of any active trace; its wall
+    time is accounted only under PAMPI_PROFILE."""
     import jax
 
     t0 = time.perf_counter()
     with jax.profiler.TraceAnnotation(name):
         yield
-    _times[name] += time.perf_counter() - t0
-    _counts[name] += 1
+    if enabled():
+        _times[name] += time.perf_counter() - t0
+        _counts[name] += 1
+
+
+def scoped(name: str, fn):
+    """`fn` traced under jax.named_scope(name): every op it lowers to
+    carries `name` in its op_name metadata, so a device trace can tell the
+    layer apart. Metadata only: the arithmetic is unchanged."""
+    import jax
+
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+
+    return run
 
 
 def add_device_time(name: str, seconds: float, calls: int = 1) -> None:

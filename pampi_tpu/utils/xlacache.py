@@ -99,11 +99,15 @@ def enable() -> str | None:
         # aren't worth the disk round-trip)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
         # a Pallas kernel's serialized body carries its MLIR locations, so
-        # with full tracebacks every call stack that reaches a kernel is a
+        # with traceback frames every call stack that reaches a kernel is a
         # key of its own (the same chunk lowered from two call sites gave
-        # two keys, and the chip smoke's second compile missed): keep only
-        # the innermost frame, and one program keeps one key
-        jax.config.update("jax_include_full_tracebacks_in_locations", False)
+        # two keys, and the chip smoke's second compile missed): keep no
+        # frame, and one program keeps one key. Full-traceback locations
+        # with a zero frame limit still carry the name stack, so op_name
+        # keeps the `solve`/`phases` scope path and every Pallas kernel
+        # its `name=` (the innermost-frame mode drops both)
+        jax.config.update("jax_include_full_tracebacks_in_locations", True)
+        jax.config.update("jax_traceback_in_locations_limit", 0)
         jax.config.update("jax_compilation_cache_dir", path)
     except (OSError, AttributeError):
         return None

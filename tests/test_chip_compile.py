@@ -13,6 +13,8 @@ import every test file (see the on-chip-measurement guide, section 2).
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -46,13 +48,16 @@ def one_chip():
     cc.reset_cache()
 
 
-def _compile(fn, sharding, *avals):
+def _compile(fn, sharding, *avals, name=None):
     """Lower `fn` on shapes placed on the described chip and compile; the
-    compiled HLO must hold the Mosaic kernel."""
+    compiled HLO must hold the Mosaic kernel, as an instruction named after
+    the kernel's `name=` (the name a device trace shows) when given."""
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
             for a in avals]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    if name is not None:
+        assert re.search(rf"%{name}(\.\d+)? = .*tpu_custom_call", text), name
     return text
 
 
@@ -69,7 +74,7 @@ def test_sor_tblock_4096_columns(one_chip):
         interpret=False)
     padded = jax.eval_shape(lambda x: sp.pad_array(x, br, h),
                             _zeros(jmax + 2, imax + 2))
-    _compile(rb, one_chip, padded, padded)
+    _compile(rb, one_chip, padded, padded, name="sor2d_rb_tblock")
 
 
 def test_sor_quarters_4096_columns(one_chip):
@@ -81,7 +86,7 @@ def test_sor_quarters_4096_columns(one_chip):
         interpret=False)
     stacked = jax.eval_shape(lambda x: sp.pad_quarters(x, brq, h),
                              _zeros(jmax + 2, imax + 2))
-    _compile(rb, one_chip, stacked, stacked)
+    _compile(rb, one_chip, stacked, stacked, name="sor2d_rb_quarters")
 
 
 def test_fused_ns2d_pre_post_4096_columns(one_chip):
@@ -95,8 +100,8 @@ def test_fused_ns2d_pre_post_4096_columns(one_chip):
     z = jax.eval_shape(pad, _zeros(jmax + 2, imax + 2))
     offs = jax.ShapeDtypeStruct((2,), jnp.int32)
     dt11 = _zeros(1, 1)
-    _compile(pre, one_chip, offs, dt11, z, z)
-    _compile(post, one_chip, offs, dt11, z, z, z, z, z)
+    _compile(pre, one_chip, offs, dt11, z, z, name="ns2d_pre")
+    _compile(post, one_chip, offs, dt11, z, z, z, z, z, name="ns2d_post")
 
 
 def test_fused_ns3d_pre_post_128(one_chip):
@@ -112,8 +117,9 @@ def test_fused_ns3d_pre_post_128(one_chip):
     z = jax.eval_shape(pad3, _zeros(n + 2, n + 2, n + 2))
     offs = jax.ShapeDtypeStruct((3,), jnp.int32)
     dt11 = _zeros(1, 1)
-    _compile(pre, one_chip, offs, dt11, z, z, z)
-    _compile(post, one_chip, offs, dt11, z, z, z, z, z, z, z)
+    _compile(pre, one_chip, offs, dt11, z, z, z, name="ns3d_pre")
+    _compile(post, one_chip, offs, dt11, z, z, z, z, z, z, z,
+             name="ns3d_post")
 
 
 def test_sor3d_octants_128(one_chip):
@@ -125,7 +131,7 @@ def test_sor3d_octants_128(one_chip):
         interpret=False)
     stacked = jax.eval_shape(lambda x: sp3.pad_octants(x, bk, 1),
                              _zeros(n + 2, n + 2, n + 2))
-    _compile(rb, one_chip, stacked, stacked)
+    _compile(rb, one_chip, stacked, stacked, name="sor3d_rb_octants")
 
 
 def test_fused_mg_cycle_refusal_pinned(one_chip):
@@ -141,3 +147,17 @@ def test_fused_mg_cycle_refusal_pinned(one_chip):
     p = _zeros(*plane)
     with pytest.raises(NotImplementedError, match="dynamic_update_slice"):
         _compile(down, one_chip, p, p)
+
+
+def test_sor_qdist_shard_2048_columns(one_chip):
+    """The per-shard quarters kernel of the 2x2 cell (4096² over 2x2: a
+    shard 2048 wide), at two RB iterations per exchange."""
+    from pampi_tpu.ops import sor_qdist as sq
+    from pampi_tpu.parallel import quarters_dist as qd
+
+    g = qd.make_qgeom(4096, 4096, 62, 2048, 2, F32)
+    rb = sq.make_rb_iters_qdist(g, 1.0 / 4096, 1.0 / 4096, 1.7, F32,
+                                interpret=False)
+    qoffs = jax.ShapeDtypeStruct((2,), jnp.int32)
+    stacked = _zeros(4, g.rp, g.w2p)
+    _compile(rb, one_chip, qoffs, stacked, stacked, name="sor2d_rb_qdist")

@@ -124,13 +124,26 @@ def test_xla_cache_enable_and_disable(monkeypatch, tmp_path):
 
     prev = jax.config.jax_compilation_cache_dir
     tracebacks = jax.config.jax_include_full_tracebacks_in_locations
+    limit = jax.config.jax_traceback_in_locations_limit
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
         assert xlacache.enable() == str(tmp_path / "c")
         assert (tmp_path / "c").is_dir()
         assert jax.config.jax_compilation_cache_dir == str(tmp_path / "c")
-        # one program, one cache key, whatever the call stack
-        assert not jax.config.jax_include_full_tracebacks_in_locations
+        # one program, one cache key, whatever the call stack: the
+        # locations hold no frame, only the name stack, which the compiled
+        # op_name keeps (a trace's layer scopes and kernel names)
+        assert jax.config.jax_traceback_in_locations_limit == 0
+        f = jax.jit(_scoped_double)
+        x = jax.numpy.arange(4.0)
+
+        def deeper():
+            return f.lower(x)
+
+        near, far = f.lower(x), deeper()
+        assert near.as_text(debug_info=True) == far.as_text(debug_info=True)
+        assert 'op_name="jit(_scoped_double)/solve/mul"' in \
+            near.compile().as_text()
         jax.config.update("jax_compilation_cache_dir", prev)
         jax.config.update("jax_enable_compilation_cache", False)
         assert xlacache.enable() is None
@@ -141,6 +154,14 @@ def test_xla_cache_enable_and_disable(monkeypatch, tmp_path):
         jax.config.update("jax_compilation_cache_dir", prev)
         jax.config.update("jax_include_full_tracebacks_in_locations",
                           tracebacks)
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+
+
+def _scoped_double(x):
+    import jax
+
+    with jax.named_scope("solve"):
+        return x * 2.0
 
 
 def test_xla_cache_dir_unset_resolves_to_checkout(monkeypatch):
