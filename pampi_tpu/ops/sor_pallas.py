@@ -813,9 +813,9 @@ def quarters_halo(n_inner: int, dtype) -> int:
     return max(a, -(-n_inner // a) * a)
 
 
-def pad_quarters(p, block_rows_q: int, halo: int):
-    """(jmax+2, imax+2) even-shaped array -> (4, rp, W2p) stacked padded
-    quarter layout [R0, R1, B0, B1].
+def split_quarters(p):
+    """(J, I) even-shaped array -> stacked (4, J/2, I/2) parity quarters
+    [R0, R1, B0, B1].
 
     LAYOUT SAFETY: any intermediate with a size-2 dim in the minor-two
     (tiled) positions explodes — [j2, 2, i2, 2] tiles the trailing 2 to a
@@ -824,15 +824,21 @@ def pad_quarters(p, block_rows_q: int, halo: int):
     single-axis stride-2 slices (outer-dim row split is a strided DMA,
     lane split a lane gather on the halved rows), which keep every
     intermediate in a sane layout."""
-    J, I = p.shape
-    j2, i2 = J // 2, I // 2
     r_even, r_odd = p[0::2], p[1::2]
-    stacked = jnp.stack([
+    return jnp.stack([
         r_even[:, 0::2],  # R0
         r_odd[:, 1::2],   # R1
         r_even[:, 1::2],  # B0
         r_odd[:, 0::2],   # B1
     ])
+
+
+def pad_quarters(p, block_rows_q: int, halo: int):
+    """(jmax+2, imax+2) even-shaped array -> (4, rp, W2p) stacked padded
+    quarter layout [R0, R1, B0, B1] (split by `split_quarters`)."""
+    J, I = p.shape
+    j2, i2 = J // 2, I // 2
+    stacked = split_quarters(p)
     nblocks = -(-j2 // block_rows_q)
     rp = nblocks * block_rows_q + 2 * halo
     w2p = -(-i2 // LANE) * LANE

@@ -66,11 +66,52 @@ def test_twin_bitwise_matches_interpret_kernel():
         np.testing.assert_allclose(float(t_r), float(k_r), rtol=1e-12)
 
 
-def test_pack_unpack_roundtrip():
-    g = qd.make_qgeom(32, 32, 16, 8, 2, jnp.float64)
-    ext = jnp.asarray(np.random.default_rng(0).standard_normal((18, 10)))
+@pytest.mark.parametrize("dtype", [jnp.float64, jnp.float32])
+@pytest.mark.parametrize("jl,il", [(16, 8), (8, 20)])
+def test_pack_unpack_roundtrip(jl, il, dtype):
+    g = qd.make_qgeom(32, 40, jl, il, 2, dtype)
+    ext = jnp.asarray(
+        np.random.default_rng(0).standard_normal((jl + 2, il + 2)), dtype
+    )
     out = qd.unpack_q_to_ext(qd.pack_ext_to_q(ext, g), g)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ext))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float64, jnp.float32])
+@pytest.mark.parametrize("jl,il", [(16, 8), (12, 36)])
+def test_pack_matches_numpy_construction(jl, il, dtype):
+    """The stacked plane, bit for bit: zeros, with each slot's parity
+    quarter ext[pr::2, pc::2] at [row_base, col_base]."""
+    g = qd.make_qgeom(48, 72, jl, il, 2, dtype)
+    ext = np.random.default_rng(5).standard_normal((jl + 2, il + 2))
+    ext = ext.astype(np.dtype(dtype))
+    want = np.zeros((4, g.rp, g.w2p), ext.dtype)
+    for slot, (pr, pc) in enumerate(qd.SLOT_PARITY):
+        q = ext[pr::2, pc::2]
+        want[slot, g.row_base : g.row_base + q.shape[0],
+             g.col_base : g.col_base + q.shape[1]] = q
+    got = np.asarray(qd.pack_ext_to_q(jnp.asarray(ext), g))
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got, want)
+
+
+def test_pack_lowers_without_elementwise_gather():
+    """A 2-D stride-2 index lowers to a gather that collapses both axes
+    (1×1 slices through an index vector of every cell) — ~90% of a 4096²
+    step on a v5e 2x2 mesh. The pack must not contain one."""
+    import re
+
+    g = qd.make_qgeom(64, 64, 32, 16, 2, jnp.float32)
+    ext = jax.ShapeDtypeStruct((34, 18), jnp.float32)
+    txt = jax.jit(qd.pack_ext_to_q, static_argnums=1).lower(ext, g).as_text()
+    collapsed = [
+        {int(d) for d in m.group(1).split(",") if d.strip()}
+        for m in re.finditer(
+            r"stablehlo\.gather.*?collapsed_slice_dims = \[([^\]]*)\]",
+            txt, re.S,
+        )
+    ]
+    assert not [c for c in collapsed if {0, 1} <= c], collapsed
 
 
 @pytest.mark.parametrize("dims", [(2, 4), (1, 8), (8, 1), (2, 2)])
