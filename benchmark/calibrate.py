@@ -30,35 +30,30 @@ def control_readings(cell: dict, seed: int, ref=None) -> dict:
     """The control's readings for one seed: bfloat16 reference against
     the float32 one."""
     import harness
-    import seedfields
 
-    par, traffic = cell["par"], cell["traffic_file"]
-    names = seedfields.field_names(par)
     if ref is None:
-        ref = harness.reference_fields(par, traffic, seed, "float32")
-    ctrl, ctrl_steps = harness.reference_fields(par, traffic, seed, "bfloat16")
-    return harness.readings(dict(zip(names, ctrl)), ctrl_steps, ref[0],
-                            ref[1], names)
+        ref = harness.reference_fields(cell, seed, "float32")
+    ctrl, ctrl_steps = harness.reference_fields(cell, seed, "bfloat16")
+    names = cell["reference"].field_names(cell["par"])
+    return harness.readings(cell, dict(zip(names, ctrl)), ctrl_steps,
+                            ref[0], ref[1])
 
 
 def calibrate(cell: dict, seeds, control_seeds, log=None):
     import harness
     import run
-    import seedfields
 
     run.devices_for(cell["chips"])
-    par, traffic = cell["par"], cell["traffic_file"]
-    names = seedfields.field_names(par)
     solver = harness.setup(cell, seeds[0])
     out = {"program": {}, "control": {}}
     for seed in seeds:
         t0 = time.perf_counter()
-        harness.place_seed(solver, par, traffic, seed)
+        harness.place_seed(solver, cell, seed)
         harness.reset(solver)
         n = harness.replay(solver)
         prog = solver.global_fields()
-        ref = harness.reference_fields(par, traffic, seed, "float32")
-        rd = harness.readings(prog, n, ref[0], ref[1], names)
+        ref = harness.reference_fields(cell, seed, "float32")
+        rd = harness.readings(cell, prog, n, ref[0], ref[1])
         out["program"][str(seed)] = rd
         line = {"seed": seed, "program": rd, "steps": n,
                 "s": time.perf_counter() - t0}

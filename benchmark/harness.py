@@ -7,22 +7,19 @@ The system under test is reached through its CLI entry,
 zero initial state. Each replay then restores the seeded initial state
 and runs the same segment through the solver's own `run`, the CLI's time
 loop (models/_driver.drive_chunks).
+
+Everything that depends on the configuration's physics comes from its
+reference module, `cell["reference"]` (benchmark/references/<config>.py):
+which .par settings it covers, the field names, the seeded initial
+fields, the reference segment, and optionally the cells compared.
 """
 
 from __future__ import annotations
 
 import contextlib
-import os
 import sys
 
 import numpy as np
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-if HERE not in sys.path:
-    sys.path.insert(0, HERE)
-
-import reference  # noqa: E402
-import seedfields  # noqa: E402
 
 # the program's own switches: each would compile a different chunk (or
 # arm test-only faults), so every run clears them
@@ -75,21 +72,20 @@ def setup(cell: dict, seed: int):
     from pampi_tpu import cli
 
     par = cell["par"]
-    reference.check_covered(par)
+    cell["reference"].check_covered(par)
     with contextlib.redirect_stdout(sys.stderr):
         rc, solver = cli.run(parameter(par), config=cell["name"], write=False)
     if rc != 0 or solver is None:
         raise RuntimeError(f"the CLI run of {cell['name']} exited {rc}")
-    return place_seed(solver, par, cell["traffic_file"], seed)
+    return place_seed(solver, cell, seed)
 
 
-def place_seed(solver, par: dict, traffic: dict, seed: int):
+def place_seed(solver, cell: dict, seed: int):
     """Give the solver the seeded initial fields, placed as its own
     (possibly sharded) arrays, and remember them for the replays."""
-    pert = traffic["perturbation"]
-    coef = seedfields.coefficients(seed, pert["modes"])
-    names = seedfields.field_names(par)
-    fields = seedfields.make_fields(par, coef, pert["amplitude"])
+    ref, par = cell["reference"], cell["par"]
+    names = ref.field_names(par)
+    fields = ref.initial_fields(par, cell["traffic_file"], seed)
     solver.set_global_fields(dict(zip(names, fields)))
     solver._bench_initial = {f: getattr(solver, f) for f in names}
     del fields
@@ -121,16 +117,19 @@ def peak_bytes(devices) -> int:
     return int(max(peaks))
 
 
-def gaps(prog: dict, ref: tuple, names) -> dict:
+def gaps(prog: dict, ref: tuple, names, compared=None) -> dict:
     """Per field, the largest gap between the program's and the
     reference's interior values over the reference's largest magnitude
-    (pressure with its interior mean removed: the Neumann problem fixes
-    it only up to a constant)."""
+    (pressure with its mean over those cells removed: the Neumann problem
+    fixes it only up to a constant). `compared`, a boolean array over the interior cells,
+    keeps the cells it marks; without it every interior cell counts."""
     out = {}
     for f, r in zip(names, ref):
         inner = (slice(1, -1),) * r.ndim
         x = np.asarray(prog[f], np.float64)[inner]
         y = np.asarray(r, np.float64)[inner]
+        if compared is not None:
+            x, y = x[compared], y[compared]
         if f == "p":
             x = x - x.mean()
             y = y - y.mean()
@@ -142,23 +141,26 @@ def gaps(prog: dict, ref: tuple, names) -> dict:
     return out
 
 
-def reference_fields(par: dict, traffic: dict, seed: int, dtype_name: str):
+def reference_fields(cell: dict, seed: int, dtype_name: str):
     """The reference segment from the seed's initial fields, in the
     stated storage type; returns (fields as numpy float32, steps)."""
     import jax.numpy as jnp
 
-    pert = traffic["perturbation"]
-    coef = seedfields.coefficients(seed, pert["modes"])
-    fields = seedfields.make_fields(par, coef, pert["amplitude"])
-    out, steps = reference.run_segment(par, fields, getattr(jnp, dtype_name))
+    ref, par = cell["reference"], cell["par"]
+    fields = ref.initial_fields(par, cell["traffic_file"], seed)
+    out, steps = ref.run_segment(par, fields, getattr(jnp, dtype_name))
     return tuple(np.asarray(a) for a in out), steps
 
 
-def readings(prog: dict, prog_steps: int, ref: tuple, ref_steps: int,
-             names) -> dict:
-    """The numbers the limits hold: each field's gap and the difference
-    in steps."""
-    out = gaps(prog, ref, names)
+def readings(cell: dict, prog: dict, prog_steps: int, ref: tuple,
+             ref_steps: int) -> dict:
+    """The numbers the limits hold: each field's gap over the cells the
+    reference module compares, and the difference in steps."""
+    mod, par = cell["reference"], cell["par"]
+    compared = getattr(mod, "compared", None)
+    if compared is not None:
+        compared = np.asarray(compared(par), bool)
+    out = gaps(prog, ref, mod.field_names(par), compared)
     out["steps_gap"] = float(abs(prog_steps - ref_steps))
     return out
 

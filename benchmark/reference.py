@@ -436,11 +436,12 @@ def run_segment(par: dict, fields: tuple, dtype=jnp.float32):
     return tuple(a.astype(jnp.float32) for a in out), int(steps)
 
 
-def check_covered(par: dict) -> None:
-    """Refuse configurations the reference does not cover."""
-    if par["name"] not in ("dcavity", "dcavity3d"):
-        raise ValueError(f"the reference covers the lid-driven cavity only, "
-                         f"not {par['name']!r}")
+def check_covered(par: dict, name: str) -> None:
+    """Refuse settings the reference does not cover: another case than
+    `name`, another solver than red-black SOR, a fixed time step."""
+    if par["name"] != name:
+        raise ValueError(f"this reference covers the lid-driven cavity "
+                         f"{name!r} only, not {par['name']!r}")
     if par.get("tpu_solver", "sor") != "sor":
         raise ValueError("the reference solves with red-black SOR only")
     if par.get("tau", 0.5) <= 0 or not math.isfinite(par["te"]):

@@ -14,9 +14,10 @@ covers the first replays of the window and the per-layer metrics are
 reduced from it; the end-to-end metrics are then not reported.
 
 After the window the fleet's peak device memory is read, the program's
-state is freed, and the reference (benchmark/reference.py) runs the same
-segment from the same seeded fields; `correct` holds each gap to the
-cell's limit (benchmark/limits/<cell>.json). The last stdout line is the
+state is freed, and the configuration's reference
+(benchmark/references/<config>.py) runs the same segment from the same
+seeded fields; `correct` holds each gap to the cell's limit
+(benchmark/limits/<cell>.json). The last stdout line is the
 result as JSON; the last stderr lines are the compared numbers with
 their limits.
 
@@ -134,7 +135,6 @@ def measure(cell: dict, seed: int, seconds: float, trace: bool) -> dict:
         jax.profiler.stop_trace()
     window_compiles = mon.compiles - compiles_setup
     peak = harness.peak_bytes(used)
-    names = tuple(solver._bench_initial)
     prog = solver.global_fields()
     prog_steps = solver.nt
     del solver
@@ -142,8 +142,8 @@ def measure(cell: dict, seed: int, seconds: float, trace: bool) -> dict:
     jax.clear_caches()
 
     ref, ref_steps = harness.reference_fields(
-        cell["par"], traffic, seed, cell["par"].get("tpu_dtype", "float32"))
-    values = harness.readings(prog, prog_steps, ref, ref_steps, names)
+        cell, seed, cell["par"].get("tpu_dtype", "float32"))
+    values = harness.readings(cell, prog, prog_steps, ref, ref_steps)
     checks = harness.judge(values, cell["limits"]["limits"])
     bad_replays = sum(1 for s in steps if s != ref_steps)
     correct = harness.passed(checks) and bad_replays == 0

@@ -71,7 +71,3 @@ def make_fields(par: dict, coef, amplitude: float):
         return u * scale, v * scale, jnp.zeros(shape, jnp.float32)
 
     return jax.jit(build)(jnp.asarray(coef))
-
-
-def field_names(par: dict) -> tuple:
-    return ("u", "v", "w", "p") if par["name"].endswith("3d") else ("u", "v", "p")

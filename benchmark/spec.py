@@ -2,6 +2,7 @@
 
   BENCHMARK.json                 the cells and metrics (checkout root)
   benchmark/configs/<c>.json     a configuration: physics keys, source
+  benchmark/references/<c>.py    the plain reference of a configuration
   benchmark/traffic/<t>.json     a mix: grid, solve budget, mesh, segment
   benchmark/limits/<cell>.json   the correctness limits of one cell
   benchmark/metrics/<m>.py       one reader per per-layer metric
@@ -38,10 +39,22 @@ def _named(kind: str, name: str, ext: str = ".json", here: str = HERE) -> str:
     return path
 
 
+def _module(kind: str, name: str, here: str = HERE):
+    """The module in `<here>/<kind>/<name>.py`, loaded by its path."""
+    path = _named(kind, name, ext=".py", here=here)
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def cell(name: str, bench: dict | None = None, here: str = HERE) -> dict:
     """The workload entry of a cell, with its configuration, traffic and
-    limits loaded, and `par`: the .par keys of the run (configuration
-    keys, then the traffic's)."""
+    limits loaded, `par`: the .par keys of the run (configuration keys,
+    then the traffic's), and `reference`: the configuration's reference
+    module (`check_covered`, `field_names`, `initial_fields`,
+    `run_segment`, and optionally `compared`)."""
     bench = benchmark() if bench is None else bench
     entries = [w for w in bench["workloads"] if w["name"] == name]
     if not entries:
@@ -52,6 +65,7 @@ def cell(name: str, bench: dict | None = None, here: str = HERE) -> dict:
                                               cfg_entry["file"]))
     w["traffic_file"] = load_json(_named("traffic", w["traffic"], here=here))
     w["limits"] = load_json(_named("limits", name, here=here))
+    w["reference"] = _module("references", w["config"], here=here)
     par = dict(w["config_file"]["par"])
     par.update(w["traffic_file"]["par"])
     w["par"] = par
@@ -68,12 +82,7 @@ def metrics_for(bench: dict, cell_name: str, section: str) -> list:
 
 def reader(metric: str, here: str = HERE):
     """The `read(ctx)` function of a per-layer metric's own file."""
-    path = _named("metrics", metric, ext=".py", here=here)
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module("metrics", metric, here=here).read
 
 
 def layer_rules(here: str = HERE) -> list:

@@ -39,14 +39,16 @@ def test_every_cell_loads():
 
 
 def test_added_files_are_found_without_edits(copy):
-    """A new configuration, traffic mix, cell limits, metric and layer,
-    each dropped in as a file of its own; only BENCHMARK.json gains
-    entries, and no existing file changes."""
+    """A new configuration with its reference, traffic mix, cell limits,
+    metric and layer, each dropped in as a file of its own; only
+    BENCHMARK.json gains entries, and no existing file changes."""
     here = copy / "benchmark"
     before = {p: p.read_bytes() for p in here.rglob("*") if p.is_file()}
     cfg = json.loads((here / "configs" / "dcavity2d.json").read_text())
     cfg["par"]["re"] = 100.0
     (here / "configs" / "cavity_re100.json").write_text(json.dumps(cfg))
+    (here / "references" / "cavity_re100.py").write_bytes(
+        (here / "references" / "dcavity2d.py").read_bytes())
     traffic = json.loads((here / "traffic" / "n256-converged.json")
                          .read_text())
     traffic["par"]["imax"] = traffic["par"]["jmax"] = 512
@@ -70,6 +72,7 @@ def test_added_files_are_found_without_edits(copy):
                                "layer": "device", "moves": "ms_per_step"})
     cell = spec.cell("cavity_re100.n512", bench, here=str(here))
     assert cell["par"]["re"] == 100.0 and cell["par"]["imax"] == 512
+    assert cell["reference"].field_names(cell["par"]) == ("u", "v", "p")
     names = [m["name"] for m in spec.metrics_for(bench, "cavity_re100.n512",
                                                  "per_layer")]
     assert "busy_ms" in names and "exchange_exposed_ms_per_step" not in names
