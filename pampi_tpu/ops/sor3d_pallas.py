@@ -423,7 +423,7 @@ def make_rb_iter_tblock_3d(
 def _tblock3d_octants_kernel(
     p_in,   # ANY (8, sp, jp2, ip2) stacked octants, sor_octants.BITS order
     rhs,    # ANY (8, sp, jp2, ip2)
-    p_out,  # ANY (8, sp, jp2, ip2)
+    p_out,  # ANY (8, sp, jp2, ip2), the same buffer as p_in
     res,    # SMEM (1, 1)
     pw2,    # VMEM (16, bk+2h, jp2, ip2): slot*8 + octant (Mosaic wants ≤4-D)
     rw2,    # VMEM (16, bk+2h, jp2, ip2)
@@ -486,8 +486,11 @@ def _tblock3d_octants_kernel(
         for c in load(b + 1, nslot):
             c.start()
 
-    for c in load(b, slot):
-        c.wait()
+    # block b >= 1 waited for its own load at the end of step b - 1
+    @pl.when(b == 0)
+    def _():
+        for c in load(0, 0):
+            c.wait()
 
     octs = {bits: pw2[slot * 8 + qidx[bits]] for bits in BITS}
     rhs_o = {bits: rw2[slot * 8 + qidx[bits]] for bits in BITS}
@@ -559,6 +562,18 @@ def _tblock3d_octants_kernel(
 
     for bits in BITS:
         ob2[slot * 8 + qidx[bits]] = octs[bits][h: h + bk]
+
+    # p_out aliases p_in: block b+1's lower halo [(b+1)bk, (b+1)bk + h)
+    # is the top h planes of the band store(b) writes, and must be read
+    # with its old values, so load(b+1) lands before store(b) starts. No
+    # other pair overlaps while bk >= h (the builder raises a thinner block):
+    # store(b) ends at h + (b+1)bk <= (b+2)bk where load(b+2) begins, and
+    # store(b-1) ends at h + b·bk <= (b+1)bk where load(b+1) begins.
+    @pl.when(b + 1 < nblocks)
+    def _():
+        for c in load(b + 1, nslot):
+            c.wait()
+
     for c in store(b, slot):
         c.start()
 
@@ -700,7 +715,12 @@ def make_rb_iter_tblock_3d_octants(
     `(p_stacked, rhs_stacked) -> (p_stacked', res_sumsq_of_last_iter)` on
     the (8, sp, jp2, ip2) layout of `pad_octants`. Requires even
     imax/jmax/kmax. Returns (rb_iter, block_k, halo=n_inner). Numerics:
-    ulp-equivalent to the masked paths (ops/sor_octants.py)."""
+    ulp-equivalent to the masked paths (ops/sor_octants.py).
+
+    The stack is updated in place (`input_output_aliases`), so a solve
+    loop carries it without a copy per call. The h pad planes at each end
+    are never stored and keep the padded input's values; the interior
+    and face masks never let them reach an owned cell."""
     if pltpu is None:
         return None, 0, 0
     if imax % 2 or jmax % 2 or kmax % 2:
@@ -717,6 +737,9 @@ def make_rb_iter_tblock_3d_octants(
     h = n_inner
     k2, j2, i2 = (kmax + 2) // 2, (jmax + 2) // 2, (imax + 2) // 2
     jp2, ip2 = octants_padded_ji(jmax, imax, dtype)
+    # in place, a block thinner than its halo would load planes that an
+    # earlier block has already stored: raise it to the halo
+    block_k = max(block_k, min(h, k2))
     nblocks = -(-k2 // block_k)
     sp = nblocks * block_k + 2 * h
     kernel = functools.partial(
@@ -757,6 +780,7 @@ def make_rb_iter_tblock_3d_octants(
         compiler_params=CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES
         ),
+        input_output_aliases={0: 0},
         interpret=interpret,
     )
 

@@ -648,7 +648,7 @@ def make_rb_iter_tblock(
 def _tblock_quarters_kernel(
     p_in,   # ANY (4, rp, W2p) stacked quarters [R0, R1, B0, B1]
     rhs,    # ANY (4, rp, W2p) stacked rhs quarters [F0, F1, G0, G1]
-    p_out,  # ANY (4, rp, W2p)
+    p_out,  # ANY (4, rp, W2p), the same buffer as p_in
     res,    # SMEM (1, 1)
     pw2,    # VMEM (2, 4, brq+2h, W2p) p windows, double-buffered
     rw2,    # VMEM (2, 4, brq+2h, W2p) rhs windows
@@ -712,8 +712,11 @@ def _tblock_quarters_kernel(
         for c in load(b + 1, nslot):
             c.start()
 
-    for c in load(b, slot):
-        c.wait()
+    # block b >= 1 waited for its own load at the end of step b - 1
+    @pl.when(b == 0)
+    def _():
+        for c in load(0, 0):
+            c.wait()
 
     R0, R1, B0, B1 = (pw2[slot, qi] for qi in range(4))
     F0, F1, G0, G1 = (rw2[slot, qi] for qi in range(4))
@@ -783,6 +786,18 @@ def _tblock_quarters_kernel(
         if compute_dtype is not None:
             band = band.astype(p_out.dtype)
         ob2[slot, qi] = band
+
+    # p_out aliases p_in: block b+1's lower halo [(b+1)brq, (b+1)brq + h)
+    # is the top h rows of the band store(b) writes, and must be read with
+    # its old values, so load(b+1) lands before store(b) starts. No other
+    # pair overlaps while brq >= h (the builder raises a thinner block):
+    # store(b) ends at h + (b+1)brq <= (b+2)brq where load(b+2) begins,
+    # and store(b-1) ends at h + b·brq <= (b+1)brq where load(b+1) begins.
+    @pl.when(b + 1 < nblocks)
+    def _():
+        for c in load(b + 1, nslot):
+            c.wait()
+
     for c in store(b, slot):
         c.start()
 
@@ -906,7 +921,12 @@ def make_rb_iter_tblock_quarters(
     arrays and VMEM windows are bf16 (half the bytes on the roofline's HBM
     wall), the per-block iteration runs in f32, and the residual is
     accumulated and returned in f32 (bf16's 8-bit mantissa cannot hold a
-    meaningful sum of squares)."""
+    meaningful sum of squares).
+
+    The stack is updated in place (`input_output_aliases`), so a solve
+    loop carries it without a copy per call. The h pad rows at each end
+    are never stored and keep the padded input's values; the quarter
+    masks and edge selects never let them reach an owned cell."""
     if pltpu is None:
         return None, 0, 0
     if imax % 2 or jmax % 2:
@@ -929,6 +949,9 @@ def make_rb_iter_tblock_quarters(
     dx2, dy2 = dx * dx, dy * dy
     j2, i2 = (jmax + 2) // 2, (imax + 2) // 2
     w2p = -(-i2 // LANE) * LANE
+    # in place, a block thinner than its halo would load rows that an
+    # earlier block has already stored: raise it to the (aligned) halo
+    block_rows_q = max(block_rows_q, h)
     nblocks = -(-j2 // block_rows_q)
     rp = nblocks * block_rows_q + 2 * h
     itemsize = jnp.dtype(dtype).itemsize
@@ -980,6 +1003,7 @@ def make_rb_iter_tblock_quarters(
         compiler_params=CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES
         ),
+        input_output_aliases={0: 0},
         interpret=interpret,
     )
 

@@ -207,3 +207,62 @@ def test_sor_qdist_shard_2048_columns(one_chip):
     qoffs = jax.ShapeDtypeStruct((2,), jnp.int32)
     stacked = _zeros(4, g.rp, g.w2p)
     _compile(rb, one_chip, qoffs, stacked, stacked, name="sor2d_rb_qdist")
+
+
+def _assert_carried_in_place(text, kernel):
+    """The while loop that calls `kernel` carries the kernel's first
+    operand with no copy: its body holds no copy of that operand's shape,
+    and the kernel reads the body parameter's own get-tuple-element."""
+    for name in re.findall(r" while\(.*?\bbody=%([\w.\-]+)", text):
+        start = text.index(f"\n%{name} (")
+        body = text[start:text.index("\n}\n", start)]
+        call = re.search(
+            rf"%{kernel}(?:\.\d+)? = \((\w+\[[\d,]+\])\{{[^}}]*\}}, "
+            r".*?custom-call\(%([\w.\-]+)", body)
+        if call:
+            break
+    else:
+        raise AssertionError(f"no while loop calls {kernel}")
+    stack, operand = call.groups()
+    param = re.match(rf"\n%{re.escape(name)} \(([\w.\-]+):", body).group(1)
+    layout = r"\{[^}]*\}"
+    assert not re.search(rf"= {re.escape(stack)}{layout} copy\(", body), \
+        f"the loop copies the {stack} stack"
+    assert re.search(
+        rf"%{re.escape(operand)} = {re.escape(stack)}{layout} "
+        rf"get-tuple-element\(%{re.escape(param)}\)", body), \
+        f"{kernel} does not read the loop's own {stack} carry"
+
+
+def test_sor_quarters_solve_loop_in_place_4096(one_chip, monkeypatch):
+    """The flat quarters solve loop as the 4096² cell runs it (n_inner cut
+    to 2): the kernel updates the carried stack in place, so XLA inserts
+    no copy of it per trip."""
+    from pampi_tpu.models import poisson as po
+
+    # the solver picks interpret mode off the chip; build it as the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = 4096
+    solve = po.make_solver_fn(n, n, 1.0 / n, 1.0 / n, 1.7, 0.0, 100, F32,
+                              backend="pallas", n_inner=2, layout="quarters",
+                              flat=True)
+    text = _compile(solve, one_chip, _zeros(n + 2, n + 2),
+                    _zeros(n + 2, n + 2), name="sor2d_rb_quarters")
+    _assert_carried_in_place(text, "sor2d_rb_quarters")
+
+
+def test_sor3d_octants_solve_loop_in_place_128(one_chip):
+    """The octant solve loop of the 128³ cell (n_inner cut to 2): the
+    kernel updates the carried stack in place, so XLA inserts no copy of
+    it per trip."""
+    from pampi_tpu.ops import sor3d_pallas as sp3
+
+    n = 128
+    rb, bk, h = sp3.make_rb_iter_tblock_3d_octants(
+        n, n, n, 1.0 / n, 1.0 / n, 1.0 / n, 1.8, F32, n_inner=2,
+        interpret=False)
+    solve = sp3.make_octants_solve_loop(rb, bk, h, float(n ** 3), 1e-3, 1000,
+                                        n, n, n, F32)
+    cube = _zeros(n + 2, n + 2, n + 2)
+    text = _compile(solve, one_chip, cube, cube, name="sor3d_rb_octants")
+    _assert_carried_in_place(text, "sor3d_rb_octants")

@@ -115,3 +115,39 @@ def test_octants_rejects_odd_dims():
             15, 16, 16, 1 / 15, 1 / 16, 1 / 16, 1.7, 1e-3, 10, jnp.float32,
             backend="pallas", layout="octants",
         )
+
+
+@pytest.mark.parametrize("km,jm,im,k,bko", [
+    (22, 14, 14, 2, 2),  # six blocks as thin as the halo
+    (22, 14, 14, 3, 4),  # three blocks
+])
+def test_kernel_in_place_loop_matches_oracle(km, jm, im, k, bko):
+    """The stack aliased through the kernel and carried by a jitted
+    fori_loop of 3 calls, each block's lower halo the band the block below
+    stores, vs 3·k oracle iterations. Interpret mode performs the DMAs in
+    issue order, so it cannot show a load racing a store; on the chip
+    the benchmark's `correct` check is the guard."""
+    shape = (km + 2, jm + 2, im + 2)
+    p, rhs = _rand(shape, jnp.float32, 6), _rand(shape, jnp.float32, 7)
+    dx, dy, dz = 1.0 / im, 1.0 / jm, 1.0 / km
+    factor, idx2, idy2, idz2 = sor_coefficients_3d(dx, dy, dz, 1.7)
+    rb, bk, h = sp3.make_rb_iter_tblock_3d_octants(
+        im, jm, km, dx, dy, dz, 1.7, jnp.float32, n_inner=k, block_k=bko,
+        interpret=True,
+    )
+    assert -(-((km + 2) // 2) // bk) >= 3
+
+    @jax.jit
+    def loop(po, ro):
+        return jax.lax.fori_loop(
+            0, 3, lambda _t, c: rb(c[0], ro), (po, jnp.float32(0.0)))
+
+    po, rsq = loop(sp3.pad_octants(p, bk, h), sp3.pad_octants(rhs, bk, h))
+    out = sp3.unpad_octants(po, km, jm, im, h)
+    q, qr = pack_octants(p), pack_octants(rhs)
+    for _ in range(3 * k):
+        q, osq = rb_iter_octants(q, qr, factor, idx2, idy2, idz2)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(unpack_octants(q)), rtol=0, atol=2e-5
+    )
+    assert float(rsq) == pytest.approx(float(osq), rel=1e-4)

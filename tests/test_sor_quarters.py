@@ -130,3 +130,39 @@ def test_auto_layout_falls_back_on_odd_dims():
     p, rhs = init_fields(param, problem=2, dtype=jnp.float32)
     pp, res = step(prep(p), prep(rhs))
     assert post(pp).shape == p.shape and float(res) >= 0.0
+
+
+@pytest.mark.parametrize("jm,im,k,brq", [
+    (126, 126, 4, 8),   # eight blocks as thin as the halo
+    (126, 126, 3, 16),  # four blocks
+])
+def test_kernel_in_place_loop_matches_oracle(jm, im, k, brq):
+    """The stack aliased through the kernel and carried by a jitted
+    fori_loop of 3 calls, each block's lower halo the band the block below
+    stores, vs 3·k oracle iterations. Interpret mode performs the DMAs in
+    issue order, so it cannot show a load racing a store; on the chip
+    the benchmark's `correct` check is the guard."""
+    param = Parameter(imax=im, jmax=jm)
+    p, rhs = init_fields(param, problem=2, dtype=jnp.float32)
+    dx, dy, factor, idx2, idy2 = _factor(im, jm)
+    rb, brr, h = sp.make_rb_iter_tblock_quarters(
+        im, jm, dx, dy, 1.9, jnp.float32, n_inner=k, block_rows_q=brq,
+        interpret=True,
+    )
+    assert -(-((jm + 2) // 2) // brr) >= 3
+
+    @jax.jit
+    def loop(pq, rq):
+        return jax.lax.fori_loop(
+            0, 3, lambda _t, c: rb(c[0], rq), (pq, jnp.float32(0.0)))
+
+    pq, rsq = loop(sp.pad_quarters(p, brr, h), sp.pad_quarters(rhs, brr, h))
+    out = sp.unpad_quarters(pq, jm, im, h)
+
+    q, qr = pack_quarters(p), pack_quarters(rhs)
+    for _ in range(3 * k):
+        q, osq = rb_iter_quarters(q, qr, factor, idx2, idy2)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(unpack_quarters(*q)), rtol=0, atol=2e-6
+    )
+    assert float(rsq) == pytest.approx(float(osq), rel=1e-5)
